@@ -7,6 +7,11 @@ experiments/: the full run in ``bench.json`` and one
 ``BENCH_<suite>.json`` per suite that ran (e.g. ``BENCH_fabric.json``
 for the transport-fabric numbers), so per-subsystem perf trajectories
 are diffable across PRs.
+
+All suites run in this one process. The suites that spawn TCP fleets
+(``bench_fleet``, ``bench_fabric``) place their children on the host CPU
+(``repro.launch.fleet_proc``), so no child reaches for a chip that this
+process may hold.
 """
 from __future__ import annotations
 
@@ -14,6 +19,8 @@ import argparse
 import importlib
 import json
 import os
+
+from repro.launch.cache import setup_compile_cache
 
 SUITES = ("bench_replacement", "bench_fleet", "bench_fabric",
           "bench_swap_overhead", "bench_kernels")
@@ -24,6 +31,7 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--json", default="experiments/bench.json")
     args = ap.parse_args()
+    setup_compile_cache()
 
     rows = []
     by_suite = {}

@@ -4,9 +4,9 @@ swap and a checkpoint/restore cycle mid-run.
 
     PYTHONPATH=src python examples/train_hotswap.py [--steps 300]
 
-(The full smollm-135m config runs the same code path on a real pod via
-``python -m repro.launch.train --arch smollm-135m``; the dry-run proves
-the sharded lowering.)
+(The full-width smollm-135m runs the same code path on one TPU via
+``python -m repro.launch.train --arch smollm-135m --seq 1024 --batch 8``
+and ``chip_smoke.py``; the dry-run proves the sharded lowering.)
 """
 import argparse
 import dataclasses

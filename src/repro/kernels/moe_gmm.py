@@ -19,8 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401 - dtype/memory enums
-from repro.kernels.pallas_compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gmm_kernel(lhs_ref, rhs_ref, out_ref, acc_scr):
@@ -68,7 +67,7 @@ def moe_gmm_pallas(lhs: jax.Array, rhs: jax.Array, *,
         out_specs=pl.BlockSpec((1, bc, bn), lambda e, c, n, k: (e, c, n)),
         out_shape=jax.ShapeDtypeStruct((E, C, N), lhs.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

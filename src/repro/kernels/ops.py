@@ -5,9 +5,15 @@ Model code calls these; ``impl`` selects the backend:
 * ``"ref"``      — pure-jnp oracle (tests)
 * ``"xla"``      — efficient pure-XLA path (what the CPU dry-run lowers;
                    the baseline on real hardware too)
-* ``"pallas"``   — Pallas TPU kernel; automatically runs interpret=True
-                   when the backend is CPU (numerics validation)
+* ``"pallas"``   — Pallas TPU kernel; raises on any other backend (tests
+                   call the kernels themselves with ``interpret=True``)
 * ``"auto"``     — xla on CPU, pallas on TPU
+
+Only ``rmsnorm_pallas`` has a VJP. ``ssd_scan_pallas`` and
+``moe_gmm_pallas`` are forward-only, so the training step
+(``train.step.build_ctx``) routes ``ssd`` and ``gmm`` to ``"xla"``; a
+gradient taken through their ``"pallas"`` paths fails in JAX's
+linearization.
 
 Attention additionally supports the schedule variants of the XLA path
 (``blockwise`` / ``blockwise_tri`` / ``dense``).
@@ -27,8 +33,14 @@ from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _require_tpu() -> None:
+    """A kernel behind ``impl="pallas"`` runs compiled on a TPU and is
+    never dropped into the interpreter behind the caller's back."""
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"impl='pallas' needs a TPU backend, got "
+            f"{jax.default_backend()!r}; call the kernel with "
+            f"interpret=True to run it in the Pallas interpreter")
 
 
 def _auto(impl: str) -> str:
@@ -43,7 +55,8 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
             impl: str = "auto") -> jax.Array:
     impl = _auto(impl)
     if impl == "pallas":
-        return rmsnorm_pallas(x, w, eps=eps, interpret=_interpret())
+        _require_tpu()
+        return rmsnorm_pallas(x, w, eps=eps)
     return _ref.rmsnorm_ref(x, w, eps)   # XLA fuses this fine
 
 
@@ -61,8 +74,9 @@ def attention(
     if impl == "pallas":
         assert kv_len is None, "pallas path is for static-length attention"
         assert prefix == 0 and isinstance(window, int)
+        _require_tpu()
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      scale=scale, interpret=_interpret())
+                                      scale=scale)
     if impl == "ref":
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
                                   scale=scale, kv_len=kv_len, prefix=prefix)
@@ -89,9 +103,9 @@ def ssd(
     impl = _auto(impl)
     if impl == "pallas":
         assert init_state is None, "pallas ssd starts from zero state"
+        _require_tpu()
         Dk = D if D is not None else jnp.zeros(A.shape, jnp.float32)
-        return ssd_scan_pallas(x, dt, A, Bm, Cm, Dk, chunk=chunk,
-                               interpret=_interpret())
+        return ssd_scan_pallas(x, dt, A, Bm, Cm, Dk, chunk=chunk)
     if impl == "ref":
         return _ref.ssd_ref(x, dt, A, Bm, Cm, D, init_state)
     return _xla.ssd_chunked(x, dt, A, Bm, Cm, D, init_state, chunk)
@@ -106,7 +120,8 @@ def gmm(lhs: jax.Array, rhs: jax.Array, *, impl: str = "auto") -> jax.Array:
     """Grouped matmul [E,C,K] x [E,K,N] -> [E,C,N]."""
     impl = _auto(impl)
     if impl == "pallas":
-        return moe_gmm_pallas(lhs, rhs, interpret=_interpret())
+        _require_tpu()
+        return moe_gmm_pallas(lhs, rhs)
     if impl == "ref":
         return _ref.gmm_ref(lhs, rhs)
     return _xla.gmm(lhs, rhs)

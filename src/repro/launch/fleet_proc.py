@@ -21,10 +21,20 @@ Two entry points:
 Children are started with the multiprocessing *spawn* context (never
 fork: the parent runs dozens of actor threads) and are daemonic, so an
 abandoned parent cannot leak them.
+
+Placement: one process holds the accelerator. That is the calling
+process, which runs the user/cloud side (and, beside the fleet,
+training and serving). Clients stand in for vehicles' on-board
+computers and shards for fabric nodes, so every child is placed on the
+host CPU: ``JAX_PLATFORMS=cpu`` is in its environment from the moment
+it is spawned, and a deployed ``jax.numpy`` module runs there on the
+CPU backend. This is a stated placement, not a fallback: a child never
+opens the chip that its parent holds.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import multiprocessing as mp
 import os
 import sys
@@ -41,6 +51,23 @@ def _env_json_clients() -> tuple:
     which children inherit and which would pin the whole fleet."""
     raw = os.environ.get("REPRO_WIRE_JSON_CLIENTS", "")
     return tuple(c.strip() for c in raw.split(",") if c.strip())
+
+
+@contextlib.contextmanager
+def _children_on_host_cpu():
+    """Children spawned inside this block start with ``JAX_PLATFORMS=cpu``
+    (see the module docstring); the parent's own backend is already
+    chosen or is chosen by its own environment once the block exits."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
+
 
 # ---------------------------------------------------------------------------
 # Child process entry points
@@ -237,7 +264,8 @@ def spawn_tcp_fleet(n_clients: int, *, shards: int = 1, seed: int = 0,
             }
             p = ctx.Process(target=_shard_main, args=(cfg,), daemon=True,
                             name=f"fleet-{sid}")
-            p.start()
+            with _children_on_host_cpu():
+                p.start()
             shard_procs.append(p)
         deadline = time.time() + ready_timeout_s
         while server.n_shards < shards:
@@ -279,7 +307,8 @@ def spawn_tcp_fleet(n_clients: int, *, shards: int = 1, seed: int = 0,
         }
         p = ctx.Process(target=_client_main, args=(cfg,), daemon=True,
                         name=f"fleet-client-{cid}")
-        p.start()
+        with _children_on_host_cpu():
+            p.start()
         procs.append(p)
 
     deadline = time.time() + ready_timeout_s

@@ -73,9 +73,11 @@ def build_ctx(cfg: RunConfig, mesh=None, rules=None,
         else "dense",
         moe_impl=cfg.sharding.moe_impl if cfg.sharding.moe_impl != "gshard"
         else ("ep" if mesh is not None else "dense"),
-        ssd_impl="auto",
+        # ssd_scan_pallas and moe_gmm_pallas have no VJP (kernels/ops.py):
+        # the training step takes their XLA paths
+        ssd_impl="auto" if decode else "xla",
         norm_impl="auto",
-        gmm_impl="auto",
+        gmm_impl="auto" if decode else "xla",
         tp_axis=cfg.sharding.tp_axis,
         batch_axes=cfg.sharding.batch_axes,
         remat_policy=cfg.train.remat_policy,
@@ -202,6 +204,7 @@ class HotSwapTrainStep:
         self.out_shardings = out_shardings
         self._cache: Dict[Tuple, Callable] = {}
         self._compiling: Dict[Tuple, "threading.Thread"] = {}
+        self._compile_errors: Dict[Tuple, Exception] = {}
         self._lock = __import__("threading").Lock()
         self.last_fingerprint: Optional[Tuple] = None
         self.active_fingerprint: Optional[Tuple] = None
@@ -253,7 +256,8 @@ class HotSwapTrainStep:
         def work():
             ex = self._build(fns)
             # AOT warm-up compile against the live shapes so the cutover
-            # step pays dispatch cost only
+            # step pays dispatch cost only. A compile error is kept and
+            # raised at cutover, on the caller's thread.
             try:
                 sds = jax.tree.map(
                     lambda x: jax.ShapeDtypeStruct(
@@ -261,8 +265,11 @@ class HotSwapTrainStep:
                         sharding=getattr(x, "sharding", None)),
                     (state, batch))
                 ex.lower(*sds).compile()
-            except Exception:   # noqa: BLE001 - fall back to lazy jit
-                pass
+            except Exception as e:   # noqa: BLE001 - re-raised at cutover
+                with self._lock:
+                    self._compile_errors[fp] = e
+                    self._compiling.pop(fp, None)
+                return
             with self._lock:
                 self._cache[fp] = ex
                 self._compiling.pop(fp, None)
@@ -281,6 +288,9 @@ class HotSwapTrainStep:
         with self._lock:
             ex = self._cache.get(fp)
             compiling = fp in self._compiling
+            error = self._compile_errors.pop(fp, None)
+        if error is not None:
+            raise error
         if ex is None:
             if (self.async_compile and self.active_fingerprint is not None
                     and self.active_fingerprint in self._cache):

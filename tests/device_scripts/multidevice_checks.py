@@ -25,6 +25,7 @@ from repro.models import build_model, moe
 from repro.models.blocks import ModelCtx
 from repro.optim.compression import compressed_psum
 from repro.sharding.auto import run_rules, shardings_for
+from repro.launch.mesh import make_mesh_like
 from repro.launch.specs import param_shardings
 
 FAILURES = []
@@ -39,7 +40,7 @@ def check(name, ok):
 def moe_ep_multidevice():
     cfg = dataclasses.replace(get_config("dbrx-132b").reduced(),
                               capacity_factor=8.0)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh_like((2, 4), ("data", "model"))
     p = moe.moe_init(jr.PRNGKey(0), cfg, jnp.float32)
     x = jr.normal(jr.PRNGKey(1), (4, 16, cfg.d_model))
     y_d, _ = jax.jit(lambda p, x: moe.moe_apply_dense(p, x, cfg))(p, x)
@@ -62,7 +63,7 @@ def seqshard_decode_multidevice():
         cfg = get_config(name).reduced()
         m = build_model(cfg)
         p = m.init(jr.PRNGKey(0))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh_like((2, 4), ("data", "model"))
         ctx_d = ModelCtx(attn_impl="blockwise", decode_attn_impl="dense",
                          moe_impl="dense", remat_policy="none")
         ctx_s = ModelCtx(mesh=mesh, attn_impl="blockwise",
@@ -87,7 +88,7 @@ def seqshard_decode_multidevice():
 
 
 def compressed_psum_multidevice():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh_like((8,), ("data",))
     g = {"w": jr.normal(jr.PRNGKey(2), (8, 64))}
     gs = jax.device_put(g, NamedSharding(mesh, P("data", None)))
     with jax.set_mesh(mesh):
@@ -121,7 +122,7 @@ def sharded_train_step_multidevice():
 
     losses = {}
     for tag, mesh in (("1dev", None),
-                      ("4x2", jax.make_mesh((4, 2), ("data", "model")))):
+                      ("4x2", make_mesh_like((4, 2), ("data", "model")))):
         model = build_model(run.model)
         opt = build_optimizer(run.train, run.model.param_dtype)
         state = init_state(model, opt, jr.PRNGKey(0), run)
@@ -159,7 +160,7 @@ def elastic_reshard_roundtrip():
     p = model.init(jr.PRNGKey(0))
     with tempfile.TemporaryDirectory() as td:
         path = save_tree(td, p, step=1)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh_like((2, 4), ("data", "model"))
         run = make_run_config("qwen3-0.6b", "train_4k")
         run = dataclasses.replace(run, model=cfg)
         rules = run_rules(run)

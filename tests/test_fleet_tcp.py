@@ -1,6 +1,7 @@
 """The acceptance scenario on both fabric topologies: deploy -> 3
 iterations -> mid-assignment redeploy -> rollback, with every message
 crossing the wire codec — in-proc loopback and real spawned-process TCP."""
+import os
 import time
 
 import pytest
@@ -113,3 +114,30 @@ def test_tcp_topology_rejects_unshippable_callables():
 def test_unknown_topology_rejected():
     with pytest.raises(ValueError, match="unknown topology"):
         Fleet.create(2, topology="quantum")
+
+
+BACKEND = """
+import jax
+def run(xs):
+    return jax.default_backend()
+"""
+
+
+def test_tcp_children_are_placed_on_host_cpu(monkeypatch):
+    """Spawned clients run deployed JAX code on the host CPU, whatever
+    platform the parent's environment names: the parent may hold the
+    chip, and a child must never reach for it."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    fleet = Fleet.create(2, topology="tcp")
+    try:
+        fe = fleet.frontend("u1")
+        _, done = fe.deploy_code("where", BACKEND).result(timeout=120.0)
+        assert done.status == Status.DONE
+        results, done = fe.submit_analytics(
+            "where", iterations=1, params={"n_values": 4}
+        ).result(timeout=120.0)
+        assert done.status == Status.DONE
+        assert results[0].value == ["cpu", "cpu"]
+    finally:
+        fleet.shutdown()
+    assert os.environ["JAX_PLATFORMS"] == "tpu"   # the parent's is kept

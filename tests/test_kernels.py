@@ -1,5 +1,6 @@
-"""Per-kernel correctness sweeps: Pallas (interpret=True on CPU) and the
-XLA fast paths against the pure-jnp oracles in ref.py."""
+"""Per-kernel correctness sweeps: Pallas (called with interpret=True, so
+the Pallas interpreter runs them on the CPU) and the XLA fast paths
+against the pure-jnp oracles in ref.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,6 +32,27 @@ def test_rmsnorm_pallas_vs_ref(shape, dtype):
     want = ref.rmsnorm_ref(x, w)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("shape", [(4, 128), (2, 7, 256)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rmsnorm_pallas_grad_vs_ref(shape, dtype):
+    """The custom VJP agrees with autodiff through the jnp oracle."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(9), 3)
+    x = jax.random.normal(k1, shape, dtype)
+    w = jax.random.normal(k2, shape[-1:], dtype)
+    g = jax.random.normal(k3, shape, jnp.float32)
+
+    def loss(norm):
+        return lambda x, w: jnp.sum(norm(x, w).astype(jnp.float32) * g)
+
+    got = jax.grad(loss(lambda x, w: rmsnorm_pallas(x, w, interpret=True)),
+                   argnums=(0, 1))(x, w)
+    want = jax.grad(loss(ref.rmsnorm_ref), argnums=(0, 1))(x, w)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol(dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -225,6 +247,20 @@ def test_gmm_xla_vs_ref():
 # ---------------------------------------------------------------------------
 # Dispatch layer
 # ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("call", [
+    lambda: ops.rmsnorm(jnp.ones((4, 32)), jnp.ones((32,)), impl="pallas"),
+    lambda: ops.attention(*[jnp.ones((1, 1, 8, 16))] * 3, impl="pallas"),
+    lambda: ops.ssd(*_ssd_inputs(S=16)[:5], impl="pallas"),
+    lambda: ops.gmm(jnp.ones((1, 8, 16)), jnp.ones((1, 16, 8)),
+                    impl="pallas"),
+])
+def test_ops_pallas_refuses_non_tpu_backend(call):
+    """impl="pallas" never drops into the interpreter behind the
+    caller's back; only a direct ``interpret=True`` call does."""
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        call()
+
 
 def test_ops_dispatch_auto_is_xla_on_cpu():
     x = jnp.ones((4, 32))

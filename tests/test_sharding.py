@@ -15,13 +15,8 @@ from repro.sharding.specs import make_rules
 
 @pytest.fixture()
 def mesh():
-    # abstract 16x16 mesh: no devices touched. The AbstractMesh
-    # constructor changed across jax versions: >=0.5 takes
-    # (axis_sizes, axis_names), 0.4.x takes a shape tuple of pairs.
-    try:
-        return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:
-        return jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    # abstract 16x16 mesh: no devices touched
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def rules():

@@ -1,6 +1,7 @@
 """Training-layer tests: convergence, hot-swap semantics, microbatch
 equivalence, compression, md5-tagged metrics."""
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -204,6 +205,33 @@ def run(logits, labels):
     assert seen[0] == "builtin"          # old version kept running
     assert seen[-1] == mod.md5           # eventually cut over
     assert step.stall_free_steps >= 1
+
+
+def test_async_compile_error_raised_at_cutover():
+    """A background compile that fails is not swallowed: the step that
+    would cut over to the new version raises the compile's error."""
+    run = small_run()
+    model = build_model(run.model)
+    opt = build_optimizer(run.train, run.model.param_dtype)
+    state = init_state(model, opt, jax.random.PRNGKey(0), run)
+    reg = ActiveCodeRegistry()
+    bindings = {s: reg.bind("u", s) for s in HotSwapTrainStep.SLOTS}
+    step = HotSwapTrainStep(model, run, opt, bindings, async_compile=True)
+    state, _ = step(state, batch_at(run_task(run), 0))
+    # a per-token loss: passes validation, but value_and_grad refuses a
+    # non-scalar output when the new step is traced
+    reg.deploy("u", "train_loss", """
+import jax, jax.numpy as jnp
+def run(logits, labels):
+    return jax.nn.logsumexp(logits, axis=-1)
+""")
+    deadline = time.time() + 120
+    with pytest.raises(TypeError, match="scalar"):
+        i = 1
+        while time.time() < deadline:
+            state, _ = step(state, batch_at(run_task(run), i))
+            i += 1
+    assert step.rebuilds == 1            # the failed version never ran
 
 
 def run_task(run):
