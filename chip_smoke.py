@@ -23,7 +23,9 @@ second, compile seconds, peak device bytes): they say the path runs,
 they are not benchmark results. The last line is
 ``{"ok": true, "device": {...}}``. On a backend other than a TPU, or
 when any phase fails, the script exits non-zero and prints no such
-line. The compile cache is ``repro.launch.cache``'s.
+line. The compile cache is ``repro.launch.cache``'s; compile seconds
+and persistent-cache hits come from the program's own ``jax.monitoring``
+listener (``repro.core.telemetry.compile_listener``).
 """
 from __future__ import annotations
 
@@ -32,7 +34,6 @@ import json
 import math
 import statistics
 import sys
-import threading
 import time
 from pathlib import Path
 from typing import Any, Dict
@@ -44,6 +45,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import IterationEvent, Status  # noqa: E402
 from repro.core.fleet import Fleet  # noqa: E402
+from repro.core.telemetry import compile_listener  # noqa: E402
 from repro.data.synthetic import batch_at  # noqa: E402
 from repro.launch import serve, train  # noqa: E402
 from repro.launch.cache import setup_compile_cache  # noqa: E402
@@ -82,36 +84,6 @@ class SmokeFailure(RuntimeError):
 def _check(ok: bool, what: str) -> None:
     if not ok:
         raise SmokeFailure(what)
-
-
-class CompileClock:
-    """Seconds JAX spends in backend compiles (reads of the persistent
-    compile cache included) and the number of persistent-cache hits,
-    while the ``with`` block runs."""
-
-    def __init__(self) -> None:
-        self.seconds = 0.0
-        self.cache_hits = 0
-        self._lock = threading.Lock()
-
-    def _duration(self, event: str, duration: float, **_: Any) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self.seconds += duration
-
-    def _event(self, event: str, **_: Any) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self.cache_hits += 1
-
-    def __enter__(self) -> "CompileClock":
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-        return self
-
-    def __exit__(self, *exc: Any) -> None:
-        jax.monitoring.unregister_event_duration_listener(self._duration)
-        jax.monitoring.unregister_event_listener(self._event)
 
 
 # ---------------------------------------------------------------------------
@@ -296,18 +268,20 @@ def main() -> int:
         return 2
     setup_compile_cache()
     dev = jax.devices()[0]
+    compiles = compile_listener()
     for phase in (train_phase, serve_phase, fleet_phase):
-        with CompileClock() as clock:
-            t0 = time.perf_counter()
-            try:
-                readings = phase()
-            except Exception as e:  # noqa: BLE001 - report, then fail
-                print(f"chip_smoke: {phase.__name__} failed: "
-                      f"{type(e).__name__}: {e}", file=sys.stderr)
-                raise
-            readings["wall_s"] = time.perf_counter() - t0
-        readings["compile_s"] = clock.seconds
-        readings["cache_hits"] = clock.cache_hits
+        before = compiles.totals()
+        t0 = time.perf_counter()
+        try:
+            readings = phase()
+        except Exception as e:  # noqa: BLE001 - report, then fail
+            print(f"chip_smoke: {phase.__name__} failed: "
+                  f"{type(e).__name__}: {e}", file=sys.stderr)
+            raise
+        readings["wall_s"] = time.perf_counter() - t0
+        after = compiles.totals()
+        readings["compile_s"] = after["backend_s"] - before["backend_s"]
+        readings["cache_hits"] = after["cache_hits"] - before["cache_hits"]
         readings["peak_bytes_in_use"] = _peak_bytes()
         print("smoke reading (not a benchmark result): "
               + json.dumps(readings), flush=True)

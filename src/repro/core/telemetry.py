@@ -18,6 +18,13 @@ Three pieces, one per failure mode the fleet used to hide:
   shards → clients) because TCP clients can only dial the node they
   registered with; snapshots hop back up the same path.
 
+The train and serve hot paths use the same :class:`Metrics`: :func:`timed`
+puts a leaf of host work in the profiler's trace as ``repro.<name>`` and
+in a histogram, and :func:`rebuild_span` keeps each executable rebuild
+as a span record with the compile seconds that
+:class:`CompileListener`, the process's one ``jax.monitoring``
+listener, saw on its thread.
+
 Everything hangs off one :class:`NodeTelemetry` per node, created by
 ``Fleet.create(telemetry=True)``. With ``telemetry=False`` no
 ``NodeTelemetry`` exists, no trace context is ever opened, and the
@@ -25,6 +32,7 @@ envelope path is byte-identical to the pre-observability fabric.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import sys
@@ -32,7 +40,9 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+
+import jax
 
 from repro.core import codec
 from repro.core.tracing import SpanRecorder, TraceContext
@@ -96,6 +106,154 @@ class Metrics:
             hists = {k: {"count": h[0], "sum": h[1], "min": h[2], "max": h[3]}
                      for k, h in self._hists.items()}
             return {"counters": dict(self._counters), "histograms": hists}
+
+
+# ---------------------------------------------------------------------------
+# Hot-path spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+class _Timed:
+    __slots__ = ("_metrics", "_name", "_ann", "_t0")
+
+    def __init__(self, metrics: Metrics, name: str) -> None:
+        self._metrics = metrics
+        self._name = name
+        self._ann = jax.profiler.TraceAnnotation("repro." + name)
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_Timed":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        ms = (time.perf_counter() - self._t0) * 1e3
+        self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._metrics.observe(self._name, ms)
+
+
+def timed(metrics: Metrics, name: str) -> _Timed:
+    """A host span ``repro.<name>`` in the profiler's trace (a few us
+    when no trace is being taken) whose ``perf_counter`` milliseconds go
+    to the ``<name>`` histogram of ``metrics`` when the body returns.
+
+    Place it around a leaf of host work (a batch, a slot resolve, a
+    rebuild), never around a whole step: the trace reader names an idle
+    gap by the longest span over it."""
+    return _Timed(metrics, name)
+
+
+# seconds of each compile phase that a rebuild record keeps
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class _CompileRecord:
+    """The compile events of one open rebuild record, as intervals:
+    a jit traced inside another fires its own event inside the outer
+    one's, so each phase's seconds are the length of their union."""
+
+    __slots__ = ("attrs", "intervals")
+
+    def __init__(self, attrs: Dict[str, Any]) -> None:
+        self.attrs = attrs
+        self.intervals: Dict[str, List[Tuple[float, float]]] = {}
+        for key in COMPILE_EVENTS.values():
+            attrs[key] = 0.0
+            self.intervals[key] = []
+
+    def add(self, key: str, end: float, seconds: float) -> None:
+        ivs = self.intervals[key]
+        ivs.append((end - seconds, end))
+        total, reach = 0.0, float("-inf")
+        for s, e in sorted(ivs):
+            if e > reach:
+                total += e - max(s, reach)
+                reach = e
+        self.attrs[key] = total
+
+
+class CompileListener:
+    """The process's one ``jax.monitoring`` listener. It keeps totals of
+    the compile phases and of persistent-cache hits since it was
+    installed, summed as JAX reports them, and adds each phase's
+    seconds to the rebuild records open on the thread that compiles."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._totals = dict.fromkeys(COMPILE_EVENTS.values(), 0.0)
+        self._totals["cache_hits"] = 0
+        self._open = threading.local()
+
+    def install(self) -> None:
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, duration: float, **_: Any) -> None:
+        key = COMPILE_EVENTS.get(event)
+        if key is None:
+            return
+        end = time.perf_counter()
+        with self._lock:
+            self._totals[key] += duration
+        for rec in getattr(self._open, "records", ()):
+            rec.add(key, end, duration)
+
+    def _event(self, event: str, **_: Any) -> None:
+        if event == CACHE_HIT_EVENT:
+            with self._lock:
+                self._totals["cache_hits"] += 1
+
+    def totals(self) -> Dict[str, float]:
+        with self._lock:
+            return dict(self._totals)
+
+    @contextlib.contextmanager
+    def into(self, attrs: Dict[str, Any]) -> Iterator[None]:
+        """Sets ``attrs``' ``trace_s``, ``lower_s`` and ``backend_s`` to
+        the seconds this thread spends in each phase inside the block."""
+        records = getattr(self._open, "records", None)
+        if records is None:
+            records = self._open.records = []
+        rec = _CompileRecord(attrs)
+        records.append(rec)
+        try:
+            yield
+        finally:
+            records.remove(rec)
+
+
+_compile_listener: Optional[CompileListener] = None
+_listener_lock = threading.Lock()
+
+
+def compile_listener() -> CompileListener:
+    """The process-wide listener, installed on first use."""
+    global _compile_listener
+    with _listener_lock:
+        if _compile_listener is None:
+            _compile_listener = CompileListener()
+            _compile_listener.install()
+        return _compile_listener
+
+
+@contextlib.contextmanager
+def rebuild_span(metrics: Metrics, spans: SpanRecorder, name: str,
+                 md5s: Dict[str, str]) -> Iterator[None]:
+    """A :func:`timed` span around building and first compiling one
+    executable, kept as a record in ``spans`` whose attrs hold the new
+    code's ``md5s`` and the trace, lower and backend-compile seconds that
+    fired on this thread while it was open."""
+    listener = compile_listener()
+    with timed(metrics, name), spans.span(name, md5s=dict(md5s)) as s, \
+            listener.into(s.span.attrs):
+        yield
 
 
 # ---------------------------------------------------------------------------

@@ -142,4 +142,5 @@ def flash_attention_pallas(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

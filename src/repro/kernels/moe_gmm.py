@@ -71,4 +71,5 @@ def moe_gmm_pallas(lhs: jax.Array, rhs: jax.Array, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="moe_gmm",
     )(lhs, rhs)

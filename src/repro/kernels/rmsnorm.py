@@ -45,6 +45,7 @@ def _forward(x2, w, eps: float, block_rows: int, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, w)
 
 

@@ -134,5 +134,6 @@ def ssd_scan_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xh, dth, A.astype(jnp.float32), Bm, Cm, D.astype(jnp.float32))
     return jnp.swapaxes(y, 1, 2), final

@@ -58,6 +58,7 @@ def _project_qkv(p, x, cfg: ModelConfig, positions, *, rope: bool = True):
     return q, k, v
 
 
+@jax.named_scope("attention")
 def attn_apply(
     p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig, *,
     causal: bool = True, window: int = 0, impl: str = "blockwise",
@@ -172,6 +173,7 @@ def kv_cache_axes() -> KVLayerCache:
                         ("batch", "kv_heads", "kv_seq", "head_dim"))
 
 
+@jax.named_scope("attention")
 def attn_decode(
     p: Dict[str, jax.Array], x: jax.Array, cache: KVLayerCache,
     pos: jax.Array, cfg: ModelConfig, *,
@@ -202,6 +204,7 @@ def attn_decode(
     return y, KVLayerCache(k, v)
 
 
+@jax.named_scope("attention")
 def attn_decode_seqshard(
     p: Dict[str, jax.Array], x: jax.Array, cache: KVLayerCache,
     pos: jax.Array, cfg: ModelConfig, mesh, *,

@@ -195,17 +195,19 @@ def block_prefill(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
         s_out, new_ssm = ssm.ssm_prefill(p["ssm"], h, cfg, impl=ctx.ssd_impl)
 
     if fam != "ssm":
-        positions = jnp.arange(S)
-        q, k, v = attn._project_qkv(p["attn"], h, cfg, positions)
-        new_kv = attn.KVLayerCache(
-            jax.lax.dynamic_update_slice_in_dim(
-                cache.kv.k, k.astype(cache.kv.k.dtype), 0, axis=2),
-            jax.lax.dynamic_update_slice_in_dim(
-                cache.kv.v, v.astype(cache.kv.v.dtype), 0, axis=2))
-        from repro.kernels import ops
-        a_out = ops.attention(q, k, v, causal=True, window=window,
-                              impl=ctx.attn_impl, prefix=cfg.n_meta_tokens)
-        a_out = jnp.einsum("bhsk,hkd->bsd", a_out, p["attn"]["wo"])
+        with jax.named_scope("attention"):
+            positions = jnp.arange(S)
+            q, k, v = attn._project_qkv(p["attn"], h, cfg, positions)
+            new_kv = attn.KVLayerCache(
+                jax.lax.dynamic_update_slice_in_dim(
+                    cache.kv.k, k.astype(cache.kv.k.dtype), 0, axis=2),
+                jax.lax.dynamic_update_slice_in_dim(
+                    cache.kv.v, v.astype(cache.kv.v.dtype), 0, axis=2))
+            from repro.kernels import ops
+            a_out = ops.attention(q, k, v, causal=True, window=window,
+                                  impl=ctx.attn_impl,
+                                  prefix=cfg.n_meta_tokens)
+            a_out = jnp.einsum("bhsk,hkd->bsd", a_out, p["attn"]["wo"])
 
     if fam == "ssm":
         return x + s_out, LayerCache(new_kv, new_ssm)

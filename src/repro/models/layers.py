@@ -83,6 +83,7 @@ def mlp_axes(kind: str) -> Dict[str, Tuple[str, ...]]:
     return {"w_up": ("embed", "ffn"), "w_down": ("ffn", "embed")}
 
 
+@jax.named_scope("mlp")
 def mlp_apply(p: Dict[str, jax.Array], x: jax.Array, kind: str) -> jax.Array:
     if kind == "swiglu":
         g = jnp.einsum("...d,df->...f", x, p["w_gate"])

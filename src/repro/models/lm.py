@@ -93,6 +93,7 @@ class LM:
             x = jnp.concatenate([meta, x], axis=1)
         return ctx.act(x, "batch", "seq", "embed_act")
 
+    @jax.named_scope("unembed")
     def _unembed(self, p, x: jax.Array) -> jax.Array:
         table = p["embed"] if self.cfg.tie_embeddings else p["unembed"]
         return layers.unembed(x, table)

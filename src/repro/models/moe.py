@@ -256,6 +256,7 @@ def moe_apply_ep(p, x, cfg: ModelConfig, mesh, *, tp_axis: str = "model",
     return out
 
 
+@jax.named_scope("mlp")
 def moe_apply(p, x, cfg: ModelConfig, *, impl: str = "ep", mesh=None,
               tp_axis: str = "model", batch_axes=("pod", "data"),
               gmm_impl: str = "auto") -> Tuple[jax.Array, jax.Array]:

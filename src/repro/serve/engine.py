@@ -18,6 +18,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import RunConfig
 from repro.core.registry import Binding, LocalDeployment
+from repro.core.telemetry import Metrics, rebuild_span, timed
+from repro.core.tracing import SpanRecorder
 from repro.models.blocks import ModelCtx
 from repro.train.step import build_ctx
 
@@ -42,13 +44,19 @@ def make_serve_step(model, ctx: ModelCtx, sampler: Callable) -> Callable:
     def serve_step(params, token, cache, pos, key):
         logits, new_cache = model.decode_step(params, token, cache, pos, ctx)
         key, sub = jax.random.split(key)
-        nxt = sampler(logits, sub)
+        with jax.named_scope("sampler"):
+            nxt = sampler(logits, sub)
         return nxt, new_cache, pos + 1, key
 
     return serve_step
 
 
 class ServeEngine:
+    """``metrics`` holds the ``serve.*`` counters and span histograms;
+    ``spans`` keeps one ``serve.rebuild`` record per decode step built,
+    with the sampler's md5 and its trace, lower and backend-compile
+    seconds."""
+
     def __init__(self, model, cfg: RunConfig, *,
                  sampler_binding: Optional[Binding] = None,
                  mesh=None, rules=None, max_seq: Optional[int] = None):
@@ -60,7 +68,12 @@ class ServeEngine:
         self.max_seq = max_seq or cfg.shape.seq_len
         self._cache: Dict[Tuple, Callable] = {}
         self._prefill_jit = None
-        self.rebuilds = 0
+        self.metrics = Metrics()
+        self.spans = SpanRecorder("serve")
+
+    @property
+    def rebuilds(self) -> int:
+        return int(self.metrics.counter("serve.rebuilds"))
 
     # ------------------------------------------------------------------
     def deploy_sampler(self, source: str) -> LocalDeployment:
@@ -73,41 +86,48 @@ class ServeEngine:
         return self.sampler_binding.deploy(source)
 
     def _resolve_sampler(self) -> Tuple[Tuple, Callable, str]:
-        b = self.sampler_binding
-        if b is None or (b.default is None
-                         and b.registry.resolve(b.user_id, b.slot) is None):
-            return ("sampler", "builtin", 0), default_sampler, "builtin"
-        r = b.current()
-        return r.fingerprint, (r.fn if not r.is_default
-                               else default_sampler), r.md5
+        with timed(self.metrics, "serve.resolve"):
+            b = self.sampler_binding
+            if b is None or (b.default is None and b.registry.resolve(
+                    b.user_id, b.slot) is None):
+                return ("sampler", "builtin", 0), default_sampler, "builtin"
+            r = b.current()
+            return r.fingerprint, (r.fn if not r.is_default
+                                   else default_sampler), r.md5
 
-    def _serve_step_for(self, fp, sampler) -> Callable:
+    def _step(self, fp, sampler, md5, params, tok, cache, pos, key):
+        """One decode step on the executable for ``fp``; the first call of
+        a new one (trace, lower, compile) is a ``serve.rebuild``."""
         ex = self._cache.get(fp)
-        if ex is None:
-            step = make_serve_step(self.model, self.ctx, sampler)
-            ex = jax.jit(step, donate_argnums=(2,))
-            self._cache[fp] = ex
-            self.rebuilds += 1
-        return ex
+        if ex is not None:
+            return ex(params, tok, cache, pos, key)
+        with rebuild_span(self.metrics, self.spans, "serve.rebuild",
+                          {"sampler": md5}):
+            ex = jax.jit(make_serve_step(self.model, self.ctx, sampler),
+                         donate_argnums=(2,))
+            out = ex(params, tok, cache, pos, key)
+        self._cache[fp] = ex
+        self.metrics.inc("serve.rebuilds")
+        return out
 
     # ------------------------------------------------------------------
     def prefill(self, params, prompt: jax.Array,
                 frames: Optional[jax.Array] = None):
-        B = prompt.shape[0]
-        cache = self.model.init_cache(B, self.max_seq, self.ctx)
         if self._prefill_jit is None:
-            if self.model.cfg.is_encoder_decoder:
-                fn = lambda p, t, f, c: self.model.prefill(p, t, f, c,
-                                                           self.ctx)
+            model, ctx = self.model, self.ctx
+            if model.cfg.is_encoder_decoder:
+                def prefill(p, t, f, c):
+                    return model.prefill(p, t, f, c, ctx)
             else:
-                fn = lambda p, t, c: self.model.prefill(p, t, c, self.ctx)
-            self._prefill_jit = jax.jit(fn)
-        if self.model.cfg.is_encoder_decoder:
-            logits, cache, pos = self._prefill_jit(params, prompt, frames,
-                                                   cache)
-        else:
-            logits, cache, pos = self._prefill_jit(params, prompt, cache)
-        return logits, cache, pos
+                def prefill(p, t, c):
+                    return model.prefill(p, t, c, ctx)
+            self._prefill_jit = jax.jit(prefill)
+        with timed(self.metrics, "serve.prefill"):
+            cache = self.model.init_cache(prompt.shape[0], self.max_seq,
+                                          self.ctx)
+            if self.model.cfg.is_encoder_decoder:
+                return self._prefill_jit(params, prompt, frames, cache)
+            return self._prefill_jit(params, prompt, cache)
 
     def generate(self, params, prompt: jax.Array, n_tokens: int, *,
                  frames: Optional[jax.Array] = None, seed: int = 0,
@@ -117,13 +137,15 @@ class ServeEngine:
         logits, cache, pos = self.prefill(params, prompt, frames=frames)
         key = jax.random.PRNGKey(seed)
         fp, sampler, md5 = self._resolve_sampler()
-        tok = sampler(logits, key).astype(jnp.int32)
+        with timed(self.metrics, "serve.first_token"), \
+                jax.named_scope("sampler"):
+            tok = sampler(logits, key).astype(jnp.int32)
         out = [tok]
         md5s = [md5]
         for i in range(n_tokens - 1):
             fp, sampler, md5 = self._resolve_sampler()   # swap boundary
-            step = self._serve_step_for(fp, sampler)
-            tok, cache, pos, key = step(params, tok, cache, pos, key)
+            tok, cache, pos, key = self._step(fp, sampler, md5, params, tok,
+                                              cache, pos, key)
             out.append(tok)
             md5s.append(md5)
             if on_token is not None:

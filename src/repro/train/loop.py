@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.checkpoint.store import CheckpointStore
 from repro.configs.base import RunConfig
+from repro.core.telemetry import Metrics, timed
 from repro.data.synthetic import SyntheticTask, batch_at
 from repro.train.state import TrainState
 from repro.train.step import HotSwapTrainStep
@@ -35,6 +36,12 @@ class TrainLoop:
     history: List[Dict[str, Any]] = field(default_factory=list)
     _preempted: bool = False
 
+    @property
+    def metrics(self) -> Metrics:
+        """The step's ``Metrics``, which the loop's ``train.batch`` span
+        histogram shares."""
+        return self.step_fn.metrics
+
     def install_sigterm_save(self) -> None:
         def handler(signum, frame):
             self._preempted = True
@@ -45,7 +52,8 @@ class TrainLoop:
             ) -> TrainState:
         start = int(state.step)
         for i in range(start, start + n_steps):
-            batch = batch_at(self.task, i)
+            with timed(self.metrics, "train.batch"):
+                batch = batch_at(self.task, i)
             t0 = time.perf_counter()
             state, metrics = self.step_fn(state, batch)
             metrics = {

@@ -18,6 +18,7 @@ Every step's metrics carry the md5s of the code that produced them
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -25,6 +26,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.core.registry import Binding
+from repro.core.telemetry import Metrics, rebuild_span, timed
+from repro.core.tracing import SpanRecorder
 from repro.models.blocks import ModelCtx
 from repro.optim.api import Optimizer
 from repro.optim.clip import clip_by_global_norm
@@ -112,7 +115,8 @@ def make_train_step(
 
     def loss_and_metrics(params, mb):
         logits, aux = model_forward(model, params, mb, ctx)
-        loss = loss_fn(logits, mb["labels"])
+        with jax.named_scope("loss"):
+            loss = loss_fn(logits, mb["labels"])
         total = loss + AUX_LOSS_WEIGHT * aux
         mets = metrics_fn(logits, mb["labels"])
         return total, (loss, aux, mets)
@@ -149,15 +153,16 @@ def make_train_step(
             loss, aux = ls.mean(), auxs.mean()
             mets = jax.tree.map(lambda m: m.mean(), ms)
 
-        grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
 
-        comp_state = state.comp_state
-        if compressor is not None:
-            grads, comp_state = compressor(grads, comp_state)
+            comp_state = state.comp_state
+            if compressor is not None:
+                grads, comp_state = compressor(grads, comp_state)
 
-        lr = optimizer.schedule(state.step)
-        new_params, new_opt = optimizer.update(grads, state.opt_state,
-                                               params, lr)
+            lr = optimizer.schedule(state.step)
+            new_params, new_opt = optimizer.update(grads, state.opt_state,
+                                                   params, lr)
         metrics = {"loss": loss, "aux_loss": aux, "grad_norm": gnorm,
                    "lr": lr, **mets}
         return TrainState(new_params, new_opt, comp_state,
@@ -184,6 +189,11 @@ class HotSwapTrainStep:
     "does not require interrupting ongoing assignments", strengthened to
     cover compilation too. (One-version lag during the compile window;
     the metrics' md5 tags always tell which version a step ran.)
+
+    ``metrics`` holds the ``train.*`` counters and span histograms (a
+    ``TrainLoop`` over this step adds to the same one); ``spans`` keeps
+    one ``train.rebuild`` record per executable built, with the new
+    code's md5s and its trace, lower and backend-compile seconds.
     """
 
     SLOTS = ("train_loss", "train_metrics", "grad_transform")
@@ -203,15 +213,28 @@ class HotSwapTrainStep:
         self.in_shardings = in_shardings
         self.out_shardings = out_shardings
         self._cache: Dict[Tuple, Callable] = {}
-        self._compiling: Dict[Tuple, "threading.Thread"] = {}
+        self._md5s: Dict[Tuple, Dict[str, str]] = {}
+        self._compiling: Dict[Tuple, threading.Thread] = {}
         self._compile_errors: Dict[Tuple, Exception] = {}
-        self._lock = __import__("threading").Lock()
+        self._lock = threading.Lock()
         self.last_fingerprint: Optional[Tuple] = None
         self.active_fingerprint: Optional[Tuple] = None
-        self.swap_events = 0
-        self.rebuilds = 0
-        self.stall_free_steps = 0   # steps served by old version while
-                                    # the new one compiled in background
+        self.metrics = Metrics()
+        self.spans = SpanRecorder("train")
+
+    @property
+    def swap_events(self) -> int:
+        return int(self.metrics.counter("train.swap_events"))
+
+    @property
+    def rebuilds(self) -> int:
+        return int(self.metrics.counter("train.rebuilds"))
+
+    @property
+    def stall_free_steps(self) -> int:
+        """Steps served by the old version while the new one compiled
+        in the background."""
+        return int(self.metrics.counter("train.stall_free_steps"))
 
     def _resolve(self):
         fp, fns, md5s = [], {}, {}
@@ -229,9 +252,7 @@ class HotSwapTrainStep:
             fns[slot] = r.fn if not r.is_default else None
             md5s[slot] = r.md5
         fpt = tuple(fp)
-        if not hasattr(self, "_md5s_store"):
-            self._md5s_store = {}
-        self._md5s_store[fpt] = md5s
+        self._md5s[fpt] = md5s
         return fpt, fns, md5s
 
     def _build(self, fns) -> Callable:
@@ -250,21 +271,24 @@ class HotSwapTrainStep:
             kw["donate_argnums"] = (0,)
         return jax.jit(step, **kw)
 
-    def _start_background_compile(self, fp, fns, state, batch) -> None:
-        import threading
+    def _rebuild_span(self, md5s):
+        return rebuild_span(self.metrics, self.spans, "train.rebuild", md5s)
+
+    def _start_background_compile(self, fp, fns, md5s, state, batch) -> None:
+        sds = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                jnp.shape(x), jnp.result_type(x),
+                sharding=getattr(x, "sharding", None)),
+            (state, batch))
 
         def work():
-            ex = self._build(fns)
             # AOT warm-up compile against the live shapes so the cutover
             # step pays dispatch cost only. A compile error is kept and
             # raised at cutover, on the caller's thread.
             try:
-                sds = jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(
-                        jnp.shape(x), jnp.result_type(x),
-                        sharding=getattr(x, "sharding", None)),
-                    (state, batch))
-                ex.lower(*sds).compile()
+                with self._rebuild_span(md5s):
+                    ex = self._build(fns)
+                    ex.lower(*sds).compile()
             except Exception as e:   # noqa: BLE001 - re-raised at cutover
                 with self._lock:
                     self._compile_errors[fp] = e
@@ -273,7 +297,7 @@ class HotSwapTrainStep:
             with self._lock:
                 self._cache[fp] = ex
                 self._compiling.pop(fp, None)
-                self.rebuilds += 1
+            self.metrics.inc("train.rebuilds")
 
         t = threading.Thread(target=work, daemon=True)
         self._compiling[fp] = t
@@ -281,9 +305,10 @@ class HotSwapTrainStep:
 
     def __call__(self, state: TrainState, batch
                  ) -> Tuple[TrainState, Dict[str, Any]]:
-        fp, fns, md5s = self._resolve()
+        with timed(self.metrics, "train.resolve"):
+            fp, fns, md5s = self._resolve()
         if fp != self.last_fingerprint and self.last_fingerprint is not None:
-            self.swap_events += 1
+            self.metrics.inc("train.swap_events")
         self.last_fingerprint = fp
         with self._lock:
             ex = self._cache.get(fp)
@@ -291,29 +316,34 @@ class HotSwapTrainStep:
             error = self._compile_errors.pop(fp, None)
         if error is not None:
             raise error
-        if ex is None:
-            if (self.async_compile and self.active_fingerprint is not None
-                    and self.active_fingerprint in self._cache):
-                # zero-stall: keep stepping the active version while the
-                # new one compiles in the background
-                if not compiling:
-                    with self._lock:
-                        if fp not in self._compiling:
-                            self._start_background_compile(
-                                fp, fns, state, batch)
-                fp_run = self.active_fingerprint
-                ex = self._cache[fp_run]
-                self.stall_free_steps += 1
-                # tag metrics with the md5s of the EXECUTED version —
-                # the consistency filter must see what actually ran
-                md5s = dict(self._md5s_store.get(fp_run, md5s))
-                md5s["_pending_swap"] = True
-            else:
-                ex = self._build(fns)
+        if ex is None and (self.async_compile
+                           and self.active_fingerprint is not None
+                           and self.active_fingerprint in self._cache):
+            # zero-stall: keep stepping the active version while the
+            # new one compiles in the background
+            if not compiling:
                 with self._lock:
-                    self._cache[fp] = ex
-                self.rebuilds += 1
-                self.active_fingerprint = fp
+                    if fp not in self._compiling:
+                        self._start_background_compile(
+                            fp, fns, md5s, state, batch)
+            fp_run = self.active_fingerprint
+            ex = self._cache[fp_run]
+            self.metrics.inc("train.stall_free_steps")
+            # tag metrics with the md5s of the EXECUTED version —
+            # the consistency filter must see what actually ran
+            md5s = dict(self._md5s.get(fp_run, md5s))
+            md5s["_pending_swap"] = True
+        elif ex is None:
+            # the first call traces, lowers and compiles the new step
+            with self._rebuild_span(md5s):
+                ex = self._build(fns)
+                new_state, metrics = ex(state, batch)
+            with self._lock:
+                self._cache[fp] = ex
+            self.metrics.inc("train.rebuilds")
+            self.active_fingerprint = fp
+            metrics["code_md5"] = md5s
+            return new_state, metrics
         else:
             self.active_fingerprint = fp
         new_state, metrics = ex(state, batch)

@@ -7,6 +7,8 @@ topology is described inside a fixture, never at import, and the
 persistent compile cache is off around the compiles (an entry written
 for a described chip cannot be read back without one).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -40,16 +42,24 @@ def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _kernel_named(text: str, name: str) -> bool:
+    """The compiled program runs a Mosaic kernel whose instruction
+    carries the ``pallas_call``'s ``name=``, as the trace shows it."""
+    return re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(.*"
+                     r"custom_call_target=\"tpu_custom_call\"", text) \
+        is not None
+
+
 def test_rmsnorm_fwd_and_grad_compile(one_chip):
     x = jax.ShapeDtypeStruct((8192, 576), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((576,), jnp.float32, sharding=one_chip)
-    assert "tpu_custom_call" in _compiled_text(rmsnorm_pallas, x, w)
+    assert _kernel_named(_compiled_text(rmsnorm_pallas, x, w), "rmsnorm")
 
     def loss(x, w):
         return jnp.sum(rmsnorm_pallas(x, w).astype(jnp.float32) ** 2)
 
-    assert "tpu_custom_call" in _compiled_text(
-        jax.grad(loss, argnums=(0, 1)), x, w)
+    assert _kernel_named(_compiled_text(
+        jax.grad(loss, argnums=(0, 1)), x, w), "rmsnorm")
 
 
 def test_flash_attention_compiles(one_chip):
@@ -57,8 +67,8 @@ def test_flash_attention_compiles(one_chip):
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, 3, 1024, 64), jnp.bfloat16,
                               sharding=one_chip)
-    assert "tpu_custom_call" in _compiled_text(flash_attention_pallas,
-                                               q, kv, kv)
+    assert _kernel_named(_compiled_text(flash_attention_pallas, q, kv, kv),
+                         "flash_attention")
 
 
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
@@ -73,7 +83,7 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
         sds((B, S, H, P), jnp.bfloat16), sds((B, S, H)), sds((H,)),
         sds((B, S, N), jnp.bfloat16), sds((B, S, N), jnp.bfloat16),
         sds((H,)))
-    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "ssd_scan")
 
 
 def test_moe_gmm_compiles(one_chip):
@@ -81,4 +91,4 @@ def test_moe_gmm_compiles(one_chip):
                                sharding=one_chip)
     rhs = jax.ShapeDtypeStruct((8, 1024, 512), jnp.bfloat16,
                                sharding=one_chip)
-    assert "tpu_custom_call" in _compiled_text(moe_gmm_pallas, lhs, rhs)
+    assert _kernel_named(_compiled_text(moe_gmm_pallas, lhs, rhs), "moe_gmm")
