@@ -286,7 +286,7 @@ class ShardingConfig:
     batch_axes: Tuple[str, ...] = ("pod", "data")
     seq_shard_activations: bool = False  # SP: shard saved residuals' seq over model
     moe_impl: str = "gshard"           # gshard | ep_shardmap
-    attn_impl: str = "blockwise"       # blockwise | dense | pallas
+    attn_impl: str = "auto"            # auto | blockwise | dense | pallas | ctxpar
     fsdp_params: bool = True           # FSDP-shard params over data axis
 
 

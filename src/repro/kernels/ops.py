@@ -7,13 +7,15 @@ Model code calls these; ``impl`` selects the backend:
                    the baseline on real hardware too)
 * ``"pallas"``   — Pallas TPU kernel; raises on any other backend (tests
                    call the kernels themselves with ``interpret=True``)
-* ``"auto"``     — xla on CPU, pallas on TPU
+* ``"auto"``     — xla on CPU, pallas on TPU; for attention, the Pallas
+                   kernel only where ``flash_qualifies`` says it can take
+                   the call, the blockwise XLA path otherwise
 
-Only ``rmsnorm_pallas`` has a VJP. ``ssd_scan_pallas`` and
-``moe_gmm_pallas`` are forward-only, so the training step
-(``train.step.build_ctx``) routes ``ssd`` and ``gmm`` to ``"xla"``; a
-gradient taken through their ``"pallas"`` paths fails in JAX's
-linearization.
+``rmsnorm_pallas`` and ``flash_attention_pallas`` have VJPs.
+``ssd_scan_pallas`` and ``moe_gmm_pallas`` are forward-only, so the
+training step (``train.step.build_ctx``) routes ``ssd`` and ``gmm`` to
+``"xla"``; a gradient taken through their ``"pallas"`` paths fails in
+JAX's linearization.
 
 Attention additionally supports the schedule variants of the XLA path
 (``blockwise`` / ``blockwise_tri`` / ``dense``).
@@ -49,6 +51,21 @@ def _auto(impl: str) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+def flash_qualifies(q: jax.Array, k: jax.Array, *, window=0, prefix: int = 0,
+                    kv_len=None, q_start=None, mesh=None) -> bool:
+    """The flash kernel can take this attention call: a TPU backend, an
+    unsharded call (no mesh, or a mesh of one device), a static window,
+    no always-visible prefix, no dynamic KV length or query offset, and
+    sequence and head sizes that tile."""
+    Sq, D = q.shape[2], q.shape[3]
+    Skv = k.shape[2]
+    return (jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1)
+            and isinstance(window, int)
+            and prefix == 0 and kv_len is None and q_start is None
+            and Sq % 128 == 0 and Skv % 128 == 0 and D in (64, 128))
+
+
 # ---------------------------------------------------------------------------
 
 def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
@@ -65,14 +82,21 @@ def attention(
     causal: bool = True, window: int = 0, scale: Optional[float] = None,
     impl: str = "auto", block_kv: int = 512,
     kv_len: Optional[jax.Array] = None, prefix: int = 0,
+    q_start=None, mesh=None,
 ) -> jax.Array:
     """q [B,Hq,Sq,D]; k,v [B,Hkv,Skv,D]. ``kv_len`` masks a dynamic KV
     prefix (decode); only dense/blockwise support it. ``window`` may be a
     traced scalar for the xla paths (0 => full); ``prefix`` keys are
-    always visible (hymba meta tokens)."""
-    impl = _auto(impl)
+    always visible (hymba meta tokens); ``q_start`` overrides the queries'
+    absolute start (blockwise only). ``mesh`` is the mesh the call runs
+    under, which ``"auto"`` reads."""
+    if impl == "auto":
+        impl = "pallas" if flash_qualifies(
+            q, k, window=window, prefix=prefix, kv_len=kv_len,
+            q_start=q_start, mesh=mesh) else "blockwise"
     if impl == "pallas":
-        assert kv_len is None, "pallas path is for static-length attention"
+        assert kv_len is None and q_start is None, \
+            "pallas path is for static-length attention"
         assert prefix == 0 and isinstance(window, int)
         _require_tpu()
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
@@ -87,11 +111,13 @@ def attention(
             and (prefix == 0 or window == 0)):
         return _xla.attention_blockwise(q, k, v, causal=causal, window=window,
                                         scale=scale, block_kv=block_kv,
-                                        triangular=True, prefix=prefix)
+                                        triangular=True, prefix=prefix,
+                                        q_start=q_start)
     # default xla / blockwise (also blockwise_tri fallback for traced window)
     return _xla.attention_blockwise(q, k, v, causal=causal, window=window,
                                     scale=scale, block_kv=block_kv,
-                                    kv_len=kv_len, prefix=prefix)
+                                    kv_len=kv_len, prefix=prefix,
+                                    q_start=q_start)
 
 
 def ssd(

@@ -90,7 +90,7 @@ def attn_apply(
                           batch_axes=batch_axes)
     else:
         out = ops.attention(q, k, v, causal=causal, window=window,
-                            impl=impl, prefix=prefix)
+                            impl=impl, prefix=prefix, mesh=mesh)
     return jnp.einsum("bhsk,hkd->bsd", out, p["wo"])
 
 
@@ -109,7 +109,6 @@ def attn_ctxpar(q, k, v, mesh, *, axis: str = "model", causal: bool = True,
     compute saving. Exact: masking uses absolute positions via q_start.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.kernels.xla import attention_blockwise as _xla_blockwise
 
     n = mesh.shape[axis]
     S = q.shape[2]
@@ -131,8 +130,9 @@ def attn_ctxpar(q, k, v, mesh, *, axis: str = "model", causal: bool = True,
                                  tiled=True)
         v_f = jax.lax.all_gather(v_l.astype(jnp.float32), axis, axis=2,
                                  tiled=True)
-        return _xla_blockwise(q_l, k_f, v_f, causal=causal, window=window,
-                              prefix=prefix, q_start=r * S_l)
+        return ops.attention(q_l, k_f, v_f, causal=causal, window=window,
+                             impl="blockwise", prefix=prefix,
+                             q_start=r * S_l)
 
     spec = P(bspec, None, axis, None)
     return jax.shard_map(

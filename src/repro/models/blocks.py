@@ -206,7 +206,7 @@ def block_prefill(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
             from repro.kernels import ops
             a_out = ops.attention(q, k, v, causal=True, window=window,
                                   impl=ctx.attn_impl,
-                                  prefix=cfg.n_meta_tokens)
+                                  prefix=cfg.n_meta_tokens, mesh=ctx.mesh)
             a_out = jnp.einsum("bhsk,hkd->bsd", a_out, p["attn"]["wo"])
 
     if fam == "ssm":

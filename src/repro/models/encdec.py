@@ -144,7 +144,8 @@ class EncDec:
             h = layers.rmsnorm(x, p_l["norm1"], cfg.norm_eps, ctx.norm_impl)
             x = x + ctx.act(
                 attn.attn_apply(p_l["attn"], h, cfg, causal=False,
-                                impl=ctx.attn_impl, rope=False),
+                                impl=ctx.attn_impl, rope=False,
+                                mesh=ctx.mesh),
                 "batch", "seq", "embed_act")
             h2 = layers.rmsnorm(x, p_l["norm2"], cfg.norm_eps, ctx.norm_impl)
             x = x + ctx.act(layers.mlp_apply(p_l["mlp"], h2, "gelu"),
@@ -174,13 +175,14 @@ class EncDec:
             h = layers.rmsnorm(x, p_l["norm1"], cfg.norm_eps, ctx.norm_impl)
             x = x + ctx.act(
                 attn.attn_apply(p_l["self_attn"], h, cfg, causal=True,
-                                impl=ctx.attn_impl),
+                                impl=ctx.attn_impl, mesh=ctx.mesh),
                 "batch", "seq", "embed_act")
             hx = layers.rmsnorm(x, p_l["norm_x"], cfg.norm_eps, ctx.norm_impl)
             kv = attn.cross_kv(p_l["cross_attn"], enc, cfg)
             x = x + ctx.act(
                 attn.attn_apply(p_l["cross_attn"], hx, cfg, causal=False,
-                                rope=False, kv=kv, impl=ctx.attn_impl),
+                                rope=False, kv=kv, impl=ctx.attn_impl,
+                                mesh=ctx.mesh),
                 "batch", "seq", "embed_act")
             h2 = layers.rmsnorm(x, p_l["norm2"], cfg.norm_eps, ctx.norm_impl)
             x = x + ctx.act(layers.mlp_apply(p_l["mlp"], h2, "gelu"),
@@ -238,13 +240,14 @@ class EncDec:
                     kv_cache.k, k.astype(kv_cache.k.dtype), 0, axis=2),
                 jax.lax.dynamic_update_slice_in_dim(
                     kv_cache.v, v.astype(kv_cache.v.dtype), 0, axis=2))
-            a_out = ops.attention(q, k, v, causal=True, impl=ctx.attn_impl)
+            a_out = ops.attention(q, k, v, causal=True, impl=ctx.attn_impl,
+                                  mesh=ctx.mesh)
             x = x + jnp.einsum("bhsk,hkd->bsd", a_out, p_l["self_attn"]["wo"])
             hx = layers.rmsnorm(x, p_l["norm_x"], cfg.norm_eps, ctx.norm_impl)
             ck, cv = attn.cross_kv(p_l["cross_attn"], enc, cfg)
             x = x + attn.attn_apply(p_l["cross_attn"], hx, cfg, causal=False,
                                     rope=False, kv=(ck, cv),
-                                    impl=ctx.attn_impl)
+                                    impl=ctx.attn_impl, mesh=ctx.mesh)
             h2 = layers.rmsnorm(x, p_l["norm2"], cfg.norm_eps, ctx.norm_impl)
             x = x + layers.mlp_apply(p_l["mlp"], h2, "gelu")
             return x, (new_kv, ck.astype(cache.cross_k.dtype),

@@ -84,6 +84,72 @@ def test_flash_attention_pallas_vs_ref(B, Hq, Hkv, Sq, Skv, D, causal,
                                atol=2e-5, rtol=2e-5)
 
 
+# (B, Hq, Hkv, Sq, Skv, D, causal, window, dtype); sequences of 384 and
+# 640 are 3 and 5 tiles of 128, so causal cases hold fully masked tiles
+FLASH_VJP_CASES = [
+    (1, 2, 2, 384, 384, 64, True, 0, jnp.float32),     # group 1
+    (1, 4, 2, 384, 384, 128, True, 0, jnp.bfloat16),   # group 2
+    (1, 3, 1, 384, 384, 64, False, 0, jnp.float32),    # group 3
+    (1, 3, 1, 384, 384, 128, True, 0, jnp.float32),
+    (1, 2, 1, 384, 384, 64, False, 0, jnp.bfloat16),
+    (1, 3, 1, 384, 384, 64, True, 0, jnp.bfloat16),
+    (1, 2, 2, 640, 640, 64, True, 192, jnp.float32),   # band skips tiles
+    (1, 2, 1, 128, 384, 64, True, 0, jnp.float32),     # decode offset
+    (2, 2, 2, 256, 256, 64, True, 0, jnp.float32),     # one masked tile
+]
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,Sq,Skv,D,causal,window,dtype",
+                         FLASH_VJP_CASES)
+def test_flash_attention_pallas_vjp_vs_ref(B, Hq, Hkv, Sq, Skv, D, causal,
+                                           window, dtype):
+    """Forward and (dq, dk, dv) of the custom VJP against autodiff through
+    the f32 oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (B, Hq, Sq, D), dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, Skv, D), dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, Skv, D), dtype)
+    g = jax.random.normal(ks[3], (B, Hq, Sq, D), jnp.float32)
+
+    def kernel(q, k, v):
+        return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                      interpret=True)
+
+    def oracle(q, k, v):
+        f32 = jnp.float32
+        return ref.attention_ref(q.astype(f32), k.astype(f32), v.astype(f32),
+                                 causal=causal, window=window)
+
+    o, vjp = jax.vjp(kernel, q, k, v)
+    o_ref, vjp_ref = jax.vjp(oracle, q, k, v)
+    grads = vjp(g.astype(o.dtype))
+    grads_ref = vjp_ref(g)
+    bound = 1e-2 if dtype == jnp.bfloat16 else 1e-5
+    assert o.dtype == dtype
+    assert _rel(o, o_ref) < bound
+    for name, a, b in zip("qkv", grads, grads_ref):
+        assert a.dtype == dtype, name
+        assert _rel(a, b) < bound, name
+
+
+def test_flash_blocks_fit_vmem_at_chip_widths():
+    """Tiles of up to 1024 rows where the sequence allows, under the VMEM
+    budget, at the training and prefill widths the benchmark runs."""
+    from repro.kernels import flash_attention as fa
+    assert fa.pick_blocks(4096, 4096, 64) == (1024, 1024)
+    assert fa.pick_blocks(1024, 1024, 128) == (1024, 1024)
+    assert fa.pick_blocks(384, 384, 64) == (128, 128)
+    for sq, d, itemsize in [(4096, 64, 2), (1024, 128, 2), (8192, 256, 4)]:
+        bq, bk = fa.pick_blocks(sq, sq, d, itemsize)
+        assert sq % bq == 0 and sq % bk == 0
+        assert fa._vmem_bytes(bq, bk, d, itemsize) <= fa.VMEM_BUDGET
+
+
 @pytest.mark.parametrize("B,Hq,Hkv,Sq,Skv,D,causal,window", ATTN_CASES)
 @pytest.mark.parametrize("triangular", [False, True])
 def test_blockwise_xla_vs_ref(B, Hq, Hkv, Sq, Skv, D, causal, window,
@@ -268,3 +334,55 @@ def test_ops_dispatch_auto_is_xla_on_cpu():
     np.testing.assert_allclose(np.asarray(ops.rmsnorm(x, w, impl="auto")),
                                np.asarray(ref.rmsnorm_ref(x, w)),
                                atol=1e-6)
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = jax.random.normal(ks[0], (1, 4, 256, 64), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 2, 256, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 2, 256, 64), jnp.float32)
+    assert jnp.array_equal(ops.attention(q, k, v, impl="auto"),
+                           xla.attention_blockwise(q, k, v))
+
+
+def _qualifying():
+    q = jnp.zeros((1, 4, 256, 64), jnp.bfloat16)
+    k = jnp.zeros((1, 2, 256, 64), jnp.bfloat16)
+    return q, k
+
+
+@pytest.mark.parametrize("change,takes_flash", [
+    ({}, True),
+    ({"mesh": jax.sharding.AbstractMesh((1,), ("model",))}, True),
+    ({"window": 128}, True),
+    ({"window": jnp.asarray(128)}, False),          # traced window
+    ({"prefix": 4}, False),
+    ({"kv_len": jnp.array([200], jnp.int32)}, False),
+    ({"q_start": 0}, False),
+    ({"mesh": jax.sharding.AbstractMesh((2,), ("model",))}, False),
+    ({"q": jnp.zeros((1, 4, 200, 64), jnp.bfloat16)}, False),
+    ({"q": jnp.zeros((1, 4, 256, 48), jnp.bfloat16),
+      "k": jnp.zeros((1, 2, 256, 48), jnp.bfloat16)}, False),
+])
+def test_flash_qualifies_rule(monkeypatch, change, takes_flash):
+    """Which calls ``impl="auto"`` hands the kernel, with the backend
+    steered to a TPU; every other call keeps the blockwise path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q, k = _qualifying()
+    kw = dict(change)
+    q, k = kw.pop("q", q), kw.pop("k", k)
+    assert ops.flash_qualifies(q, k, **kw) is takes_flash
+
+
+@pytest.mark.parametrize("kw", [{}, {"prefix": 4}, {"q_start": 0},
+                                {"mesh": jax.sharding.AbstractMesh(
+                                    (2,), ("model",))}])
+def test_ops_attention_auto_dispatch(monkeypatch, kw):
+    """``ops.attention(impl="auto")`` calls the kernel exactly where the
+    rule qualifies the call (backend steered to a TPU, kernels spied)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    calls = []
+    monkeypatch.setattr(ops, "flash_attention_pallas",
+                        lambda *a, **k: calls.append("flash") or a[0])
+    monkeypatch.setattr(ops._xla, "attention_blockwise",
+                        lambda *a, **k: calls.append("blockwise") or a[0])
+    q, k = _qualifying()
+    ops.attention(q, k, k, impl="auto", **kw)
+    assert calls == ["flash" if not kw else "blockwise"]

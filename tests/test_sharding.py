@@ -83,10 +83,28 @@ def test_sp_rules():
 def test_optimized_preset():
     base = make_run_config("yi-34b", "train_4k")
     opt = make_run_config("yi-34b", "train_4k", preset="optimized")
-    assert base.sharding.attn_impl == "blockwise"
+    assert base.sharding.attn_impl == "auto"
     assert opt.sharding.attn_impl == "ctxpar"
     assert opt.train.zero1 and not opt.sharding.fsdp_params
     # archs without a tuned preset fall back to baseline knobs
     same = make_run_config("dbrx-132b", "train_4k", preset="optimized")
     assert same.sharding == make_run_config("dbrx-132b",
                                             "train_4k").sharding
+
+
+def test_auto_attention_is_blockwise_off_tpu():
+    """The default ``attn_impl="auto"`` takes the blockwise XLA path on
+    any backend but a TPU, at the widths of a training run."""
+    from repro.kernels import ops, xla
+    from repro.train.step import build_ctx
+
+    ctx = build_ctx(make_run_config("smollm-135m", "train_4k"))
+    assert ctx.attn_impl == "auto"
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (1, 3, 256, 64), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 1, 256, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 1, 256, 64), jnp.float32)
+    assert not ops.flash_qualifies(q, k)
+    got = ops.attention(q, k, v, impl=ctx.attn_impl)
+    want = xla.attention_blockwise(q, k, v)
+    assert jnp.array_equal(got, want)

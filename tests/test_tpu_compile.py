@@ -71,6 +71,29 @@ def test_flash_attention_compiles(one_chip):
                          "flash_attention")
 
 
+@pytest.mark.parametrize("B,Hq,Hkv,S,D", [
+    (4, 9, 3, 4096, 64),        # smollm-135m training, 4 x 4096
+    (32, 16, 8, 1024, 128),     # qwen3-0.6b prefill, batch 32 x 1024
+])
+def test_flash_attention_fwd_and_grad_compile_at_chip_widths(
+        one_chip, B, Hq, Hkv, S, D):
+    """The forward and the two backward kernels tile and fit VMEM at the
+    widths the benchmark runs them."""
+    q = jax.ShapeDtypeStruct((B, Hq, S, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, Hkv, S, D), jnp.bfloat16,
+                              sharding=one_chip)
+    assert _kernel_named(_compiled_text(flash_attention_pallas, q, kv, kv),
+                         "flash_attention")
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_pallas(q, k, v).astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    for name in ("flash_attention", "flash_attention_dkv",
+                 "flash_attention_dq"):
+        assert _kernel_named(text, name), name
+
+
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
     # mamba2-370m: d_inner 2048 = 32 heads x 64, state 128, chunk 128
     B, S, H, P, N = 1, 2048, 32, 64, 128
