@@ -33,6 +33,7 @@ from repro.configs.chameleon_34b import CONFIG as _chameleon
 from repro.configs.mamba2_370m import CONFIG as _mamba2
 from repro.configs.whisper_large_v3 import CONFIG as _whisper
 from repro.configs.hymba_1_5b import CONFIG as _hymba
+from repro.configs.moonlight_16b_a3b import CONFIG as _moonlight
 
 ARCH_REGISTRY: Dict[str, ModelConfig] = {
     c.name: c
@@ -47,6 +48,7 @@ ARCH_REGISTRY: Dict[str, ModelConfig] = {
         _mamba2,
         _whisper,
         _hymba,
+        _moonlight,
     )
 }
 

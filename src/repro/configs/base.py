@@ -36,12 +36,24 @@ class ModelConfig:
     sliding_window: int = 0          # 0 => full attention
     global_attn_layers: Tuple[int, ...] = ()   # layers forced to full attn (hybrid)
     n_meta_tokens: int = 0           # learned always-visible prefix (hymba)
+    # --- latent attention (MLA, DeepSeek-V3); kv_lora_rank 0 => not MLA ---
+    kv_lora_rank: int = 0            # width of the cached latent c_kv
+    q_lora_rank: int = 0             # 0 => a plain q projection
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0        # roped key width, shared by all heads
+    v_head_dim: int = 0
     # --- mlp / moe ---
     d_ff: int = 0                    # dense FFN hidden (0 for pure-ssm)
     num_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim
     capacity_factor: float = 1.25
+    # softmax | sigmoid (DeepSeek-V3's noaux_tc: a score-correction bias
+    # in the choice only); the chosen experts' gates are renormalised
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    n_shared_experts: int = 0        # one SwiGLU MLP of n x moe_d_ff
+    first_k_dense: int = 0           # leading layers with a dense d_ff MLP
     # --- ssm (mamba2 SSD) ---
     ssm_state: int = 0               # N
     ssm_heads: int = 0
@@ -87,6 +99,21 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def qk_head_dim(self) -> int:
+        """Width of a query or key head: latent attention's nope + rope
+        parts, else ``hd()``."""
+        if self.is_mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.hd()
+
+    def latent_width(self) -> int:
+        """Values an MLA layer caches per position: c_kv and k_pe."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
     def d_inner(self) -> int:
         """SSM inner width."""
         return self.ssm_expand * self.d_model
@@ -99,6 +126,12 @@ class ModelConfig:
             assert self.num_heads % self.num_kv_heads == 0
         if self.is_moe:
             assert self.experts_per_token > 0 and self.moe_d_ff > 0
+            assert self.router_scoring in ("softmax", "sigmoid")
+            assert 0 <= self.first_k_dense < self.num_layers
+            assert self.d_ff > 0 or not self.first_k_dense
+        if self.is_mla:
+            assert self.qk_nope_head_dim > 0 and self.v_head_dim > 0
+            assert self.qk_rope_head_dim % 2 == 0
         if self.family in ("ssm", "hybrid"):
             assert self.ssm_state > 0
         if self.is_encoder_decoder:
@@ -124,7 +157,14 @@ class ModelConfig:
         if self.d_ff:
             small.update(d_ff=128)
         if self.is_moe:
-            small.update(num_experts=4, experts_per_token=2, moe_d_ff=64)
+            small.update(num_experts=4, experts_per_token=2, moe_d_ff=64,
+                         first_k_dense=min(self.first_k_dense, 1))
+            if self.first_k_dense:
+                small.update(num_layers=3)
+        if self.is_mla:
+            small.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                         qk_rope_head_dim=8, v_head_dim=16, head_dim=0,
+                         q_lora_rank=24 if self.q_lora_rank else 0)
         if self.ssm_state:
             di = small["d_model"] * self.ssm_expand
             small.update(ssm_state=16, ssm_heads=di // 16, ssm_head_dim=16,
@@ -144,7 +184,7 @@ class ModelConfig:
 
 def _param_count(c: ModelConfig, active_only: bool = False) -> int:
     d = c.d_model
-    hd = c.hd()
+    hd = c.qk_head_dim()
     n = 0
     # embeddings (+ untied unembed)
     n += c.vocab_size * d
@@ -152,6 +192,13 @@ def _param_count(c: ModelConfig, active_only: bool = False) -> int:
         n += c.vocab_size * d
 
     def attn_params() -> int:
+        if c.is_mla:
+            H, r = c.num_heads, c.kv_lora_rank
+            q = (d * H * hd if not c.q_lora_rank else
+                 d * c.q_lora_rank + c.q_lora_rank + c.q_lora_rank * H * hd)
+            kv = d * c.latent_width() + r + r * H * (c.qk_nope_head_dim
+                                                     + c.v_head_dim)
+            return q + kv + H * c.v_head_dim * d
         q = d * c.num_heads * hd
         kv = 2 * d * c.num_kv_heads * hd
         o = c.num_heads * hd * d
@@ -163,7 +210,10 @@ def _param_count(c: ModelConfig, active_only: bool = False) -> int:
 
     def moe_ffn() -> int:
         e = c.experts_per_token if active_only else c.num_experts
-        return e * 3 * d * c.moe_d_ff + d * c.num_experts  # experts + router
+        shared = dense_ffn(c.n_shared_experts * c.moe_d_ff)
+        bias = c.num_experts if c.router_scoring == "sigmoid" else 0
+        # experts + router
+        return e * 3 * d * c.moe_d_ff + d * c.num_experts + bias + shared
 
     def ssm_params() -> int:
         di = c.d_inner()
@@ -185,7 +235,8 @@ def _param_count(c: ModelConfig, active_only: bool = False) -> int:
             n += attn_params() + ssm_params() + dense_ffn(c.d_ff)
             continue
         n += attn_params()
-        n += moe_ffn() if c.is_moe else dense_ffn(c.d_ff)
+        n += (moe_ffn() if c.is_moe and layer >= c.first_k_dense
+              else dense_ffn(c.d_ff))
     if c.is_encoder_decoder:
         for _ in range(c.num_encoder_layers):
             # encoder self-attn + ffn; decoder layers above additionally carry

@@ -145,6 +145,24 @@ def timed(metrics: Metrics, name: str) -> _Timed:
     return _Timed(metrics, name)
 
 
+class StepArrays:
+    """A per-step device array (such as the tokens a decode step routed
+    to each expert), kept while ``on`` as a copy on the device: no host
+    sync per step. ``drain`` fetches what was kept, once."""
+
+    def __init__(self) -> None:
+        self.on = False
+        self._kept: List[Any] = []
+
+    def record(self, x: Optional[jax.Array]) -> None:
+        if self.on and x is not None:
+            self._kept.append(jax.numpy.copy(x))
+
+    def drain(self) -> List[Any]:
+        kept, self._kept = self._kept, []
+        return jax.device_get(kept)
+
+
 # seconds of each compile phase that a rebuild record keeps
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",

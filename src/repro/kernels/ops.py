@@ -12,10 +12,11 @@ Model code calls these; ``impl`` selects the backend:
                    the call, the blockwise XLA path otherwise
 
 ``rmsnorm_pallas`` and ``flash_attention_pallas`` have VJPs.
-``ssd_scan_pallas`` and ``moe_gmm_pallas`` are forward-only, so the
-training step (``train.step.build_ctx``) routes ``ssd`` and ``gmm`` to
-``"xla"``; a gradient taken through their ``"pallas"`` paths fails in
-JAX's linearization.
+``ssd_scan_pallas``, ``moe_gmm_pallas`` and ``mla_decode_pallas`` are
+forward-only, so the training step (``train.step.build_ctx``) routes
+``ssd`` and ``gmm`` to ``"xla"``; a gradient taken through their
+``"pallas"`` paths fails in JAX's linearization. ``ragged_gmm``'s Pallas
+path is megablox's ``gmm``, which has a VJP.
 
 Attention additionally supports the schedule variants of the XLA path
 (``blockwise`` / ``blockwise_tri`` / ``dense``).
@@ -30,6 +31,7 @@ import jax.numpy as jnp
 from repro.kernels import ref as _ref
 from repro.kernels import xla as _xla
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.mla_decode import mla_decode_pallas, mla_decode_xla
 from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
@@ -56,14 +58,15 @@ def flash_qualifies(q: jax.Array, k: jax.Array, *, window=0, prefix: int = 0,
     """The flash kernel can take this attention call: a TPU backend, an
     unsharded call (no mesh, or a mesh of one device), a static window,
     no always-visible prefix, no dynamic KV length or query offset, and
-    sequence and head sizes that tile."""
+    sequence and head sizes that tile (192 is latent attention's q/k
+    width)."""
     Sq, D = q.shape[2], q.shape[3]
     Skv = k.shape[2]
     return (jax.default_backend() == "tpu"
             and (mesh is None or mesh.size == 1)
             and isinstance(window, int)
             and prefix == 0 and kv_len is None and q_start is None
-            and Sq % 128 == 0 and Skv % 128 == 0 and D in (64, 128))
+            and Sq % 128 == 0 and Skv % 128 == 0 and D in (64, 128, 192))
 
 
 # ---------------------------------------------------------------------------
@@ -151,3 +154,41 @@ def gmm(lhs: jax.Array, rhs: jax.Array, *, impl: str = "auto") -> jax.Array:
     if impl == "ref":
         return _ref.gmm_ref(lhs, rhs)
     return _xla.gmm(lhs, rhs)
+
+
+def ragged_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
+               impl: str = "auto") -> jax.Array:
+    """Dropless grouped matmul: rows [m, K] sorted by group, rhs
+    [G, K, N], ``group_sizes`` [G] int32 summing to at most m -> [m, N]
+    in ``lhs``'s dtype (f32 accumulation); rows past the groups' sum are
+    not defined. The Pallas path is megablox's ``gmm`` with ``m`` padded
+    to its row tile."""
+    impl = _auto(impl)
+    if impl == "pallas":
+        _require_tpu()
+        from jax.experimental.pallas.ops.tpu.megablox import gmm as mb_gmm
+        m, k = lhs.shape
+        n = rhs.shape[2]
+        tm = 128 if m <= 4096 else 512
+        pad = -m % tm
+        out = mb_gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, group_sizes,
+                     preferred_element_type=lhs.dtype,
+                     tiling=(tm, min(k, 2048 if tm == 128 else 512),
+                             min(n, 512)))
+        return out[:m]
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)
+
+
+def latent_decode_attention(q: jax.Array, cache: jax.Array, pos: jax.Array,
+                            *, r: int, scale: float,
+                            impl: str = "auto") -> jax.Array:
+    """Absorbed latent-attention decode: q [B, H, C], cache [B, C, T],
+    positions <= ``pos`` attended -> [B, H, r] (see
+    ``kernels/mla_decode.py``)."""
+    impl = _auto(impl)
+    if impl == "pallas":
+        _require_tpu()
+        return mla_decode_pallas(q, cache, pos, r=r, scale=scale)
+    return mla_decode_xla(q, cache, pos, r=r, scale=scale)

@@ -299,3 +299,145 @@ def _masked_decode(q, k, v, mask):
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs,
                       vr.astype(jnp.float32)).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V3)
+# ---------------------------------------------------------------------------
+#
+# A layer caches one latent per position, [c_kv | k_pe]: the normed
+# low-rank c_kv (kv_lora_rank) and the roped key part k_pe
+# (qk_rope_head_dim), both shared by every head. The cache is laid out
+# [B, kv_lora_rank + qk_rope_head_dim, T], positions minor, so that the
+# 576-wide latent is not padded to the lanes (kernels/mla_decode.py). The prefill expands
+# c_kv through W_UK (``wk_b``) and W_UV (``wv_b``) to per-head keys and
+# values and runs causal attention at q/k width nope + rope and v width
+# v_head_dim; decode folds W_UK into the query and W_UV into the output
+# and attends over the latent rows themselves.
+
+# the q_a / kv_a norms are built with RMSNorm's default eps in the
+# DeepSeek-V3 modelling code, not the config's rms_norm_eps
+LATENT_NORM_EPS = 1e-6
+
+
+def mla_init(key, cfg: ModelConfig, dtype) -> Dict[str, jax.Array]:
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    qk, vd = cfg.qk_head_dim(), cfg.v_head_dim
+    ks = jax.random.split(key, 6)
+    p: Dict[str, jax.Array] = {}
+    if cfg.q_lora_rank:
+        p["wq_a"] = layers.dense_init(ks[0], d, (cfg.q_lora_rank,), dtype)
+        p["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), dtype)
+        p["wq_b"] = layers.dense_init(ks[1], cfg.q_lora_rank, (H, qk), dtype)
+    else:
+        p["wq"] = layers.dense_init(ks[0], d, (H, qk), dtype)
+    p["wkv_a"] = layers.dense_init(ks[2], d, (cfg.latent_width(),), dtype)
+    p["kv_a_norm"] = jnp.ones((r,), dtype)
+    p["wk_b"] = layers.dense_init(ks[3], r, (H, cfg.qk_nope_head_dim), dtype)
+    p["wv_b"] = layers.dense_init(ks[4], r, (H, vd), dtype)
+    p["wo"] = layers.trunc_normal(ks[5], (H, vd, d), (H * vd) ** -0.5, dtype)
+    return p
+
+
+def mla_axes(cfg: ModelConfig) -> Dict[str, Tuple]:
+    a: Dict[str, Tuple] = {}
+    if cfg.q_lora_rank:
+        a["wq_a"] = ("embed", None)
+        a["q_a_norm"] = (None,)
+        a["wq_b"] = (None, "heads", "head_dim")
+    else:
+        a["wq"] = ("embed", "heads", "head_dim")
+    a.update(wkv_a=("embed", None), kv_a_norm=(None,),
+             wk_b=(None, "heads", "head_dim"),
+             wv_b=(None, "heads", "head_dim"),
+             wo=("heads", "head_dim", "embed"))
+    return a
+
+
+def init_latent_cache(cfg: ModelConfig, batch: int, max_seq: int,
+                      dtype) -> jax.Array:
+    return jnp.zeros((batch, cfg.latent_width(), max_seq), dtype)
+
+
+def _rope_published(x, positions, theta):
+    """RoPE on DeepSeek-V3's interleaved layout: the modelling code
+    de-interleaves the pairs (x0, x1), (x2, x3), ... into halves and then
+    rotates halves. q and k go through the same permutation, so their dot
+    products are those of the published model."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return layers.apply_rope(x, positions, theta)
+
+
+def _mla_query(p, h, cfg: ModelConfig, positions):
+    """h [B,S,d] -> q [B,H,S,nope + rope] with its rope part rotated."""
+    if cfg.q_lora_rank:
+        qa = layers.rmsnorm(jnp.einsum("bsd,dr->bsr", h, p["wq_a"]),
+                            p["q_a_norm"], LATENT_NORM_EPS)
+        q = jnp.einsum("bsr,rhk->bhsk", qa, p["wq_b"])
+    else:
+        q = jnp.einsum("bsd,dhk->bhsk", h, p["wq"])
+    n = cfg.qk_nope_head_dim
+    return jnp.concatenate(
+        [q[..., :n], _rope_published(q[..., n:], positions, cfg.rope_theta)],
+        axis=-1)
+
+
+def _mla_latent(p, h, cfg: ModelConfig, positions):
+    """h [B,S,d] -> latent rows [B,S,r + rope]: normed c_kv, roped k_pe."""
+    kv = jnp.einsum("bsd,dc->bsc", h, p["wkv_a"])
+    r = cfg.kv_lora_rank
+    c = layers.rmsnorm(kv[..., :r], p["kv_a_norm"], LATENT_NORM_EPS)
+    k_pe = _rope_published(kv[..., r:], positions, cfg.rope_theta)
+    return jnp.concatenate([c, k_pe], axis=-1)
+
+
+@jax.named_scope("attention")
+def mla_prefill(p, h, cfg: ModelConfig, *, impl: str = "auto", mesh=None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Non-absorbed causal latent attention over a whole sequence.
+    h [B,S,d] -> (out [B,S,d], latents [B,S,r + rope] to cache)."""
+    with jax.named_scope("mla_prefill"):
+        B, S, _ = h.shape
+        positions = jnp.arange(S)
+        q = _mla_query(p, h, cfg, positions)
+        lat = _mla_latent(p, h, cfg, positions)
+        r, H = cfg.kv_lora_rank, cfg.num_heads
+        c = lat[..., :r]
+        k_pe = jnp.broadcast_to(lat[:, None, :, r:],
+                                (B, H, S, cfg.qk_rope_head_dim))
+        k = jnp.concatenate(
+            [jnp.einsum("bsr,rhk->bhsk", c, p["wk_b"]), k_pe], axis=-1)
+        v = jnp.einsum("bsr,rhk->bhsk", c, p["wv_b"])
+        # V zero-padded to the q/k width, so that one kernel (or the
+        # blockwise path) takes the call; the padding's output is dropped
+        vd, qk = cfg.v_head_dim, cfg.qk_head_dim()
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk - vd)))
+        out = ops.attention(q, k, v, causal=True, scale=qk ** -0.5,
+                            impl=impl, mesh=mesh)[..., :vd]
+        return jnp.einsum("bhsk,hkd->bsd", out, p["wo"]), lat
+
+
+@jax.named_scope("attention")
+def mla_decode(p, h, cache: jax.Array, pos, cfg: ModelConfig, *,
+               impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
+    """One absorbed decode step. h [B,1,d]; cache [B,r + rope,T]
+    latents; pos [] the new token's position. The new latent is written
+    first; the query folds in W_UK, the output W_UV, and the attention
+    reads the latents only, never per-head keys or values."""
+    with jax.named_scope("mla_decode"):
+        positions = jnp.full((1,), pos, jnp.int32)
+        q = _mla_query(p, h, cfg, positions)[:, :, 0]          # [B,H,qk]
+        lat = _mla_latent(p, h, cfg, positions)               # [B,1,C]
+        cache = jax.lax.dynamic_update_slice_in_dim(
+            cache, jnp.swapaxes(lat, 1, 2).astype(cache.dtype), pos, axis=2)
+        n, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        q_lat = jnp.einsum("bhk,rhk->bhr", q[..., :n], p["wk_b"],
+                           preferred_element_type=jnp.float32)
+        q_abs = jnp.concatenate([q_lat.astype(cache.dtype),
+                                 q[..., n:].astype(cache.dtype)], axis=-1)
+        o_lat = ops.latent_decode_attention(
+            q_abs, cache, pos, r=r, scale=cfg.qk_head_dim() ** -0.5,
+            impl=impl)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(h.dtype), p["wv_b"])
+        y = jnp.einsum("bhv,hvd->bd", o, p["wo"])
+        return y[:, None], cache

@@ -40,34 +40,60 @@ class ModelCtx:
 
 
 class LayerCache(NamedTuple):
-    """Uniform per-layer decode cache; unused fields are size-0 arrays so
-    the pytree structure is identical across layers (scan-stackable)."""
+    """Uniform per-layer decode state; unused fields are size-0 arrays so
+    the pytree structure is identical across layers (scan-stackable).
+    ``kv``: keys and values (GQA); ``ssm``: recurrent state; ``latent``:
+    latent attention's [B, kv_lora_rank + qk_rope_head_dim, T];
+    ``expert_load``: tokens the last step routed to each expert [E]."""
     kv: attn.KVLayerCache
     ssm: ssm.SSMLayerCache
+    latent: jax.Array
+    expert_load: jax.Array
+
+
+def _empty() -> jax.Array:
+    return jnp.zeros((0,), jnp.float32)
 
 
 def _empty_kv() -> attn.KVLayerCache:
-    z = jnp.zeros((0,), jnp.float32)
-    return attn.KVLayerCache(z, z)
+    return attn.KVLayerCache(_empty(), _empty())
 
 
 def _empty_ssm() -> ssm.SSMLayerCache:
-    z = jnp.zeros((0,), jnp.float32)
-    return ssm.SSMLayerCache(z, z)
+    return ssm.SSMLayerCache(_empty(), _empty())
+
+
+def _moe_mlp(cfg: ModelConfig, dense_mlp: bool) -> bool:
+    """Whether a layer's MLP is the expert layer: MoE models' layers but
+    their leading dense ones (``dense_mlp``)."""
+    return cfg.is_moe and not dense_mlp
+
+
+def _mlp(p, h, cfg: ModelConfig, ctx: ModelCtx, dense_mlp: bool):
+    """The layer's MLP: (y, aux loss, tokens per expert or size 0)."""
+    if _moe_mlp(cfg, dense_mlp):
+        return moe.moe_apply(p["moe"], h, cfg, impl=ctx.moe_impl,
+                             mesh=ctx.mesh, tp_axis=ctx.tp_axis,
+                             batch_axes=ctx.batch_axes, gmm_impl=ctx.gmm_impl)
+    kind = "gelu" if cfg.is_encoder_decoder else "swiglu"
+    return (layers.mlp_apply(p["mlp"], h, kind), jnp.zeros((), jnp.float32),
+            _empty())
 
 
 # ---------------------------------------------------------------------------
 # Init / axes
 # ---------------------------------------------------------------------------
 
-def block_init(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
+def block_init(key, cfg: ModelConfig, dtype, dense_mlp: bool = False
+               ) -> Dict[str, Any]:
     ks = jax.random.split(key, 6)
     p: Dict[str, Any] = {"norm1": jnp.ones((cfg.d_model,), dtype)}
     fam = cfg.family
     if fam == "ssm":
         p["ssm"] = ssm.ssm_init(ks[0], cfg, dtype)
         return p
-    p["attn"] = attn.attn_init(ks[1], cfg, dtype)
+    p["attn"] = (attn.mla_init(ks[1], cfg, dtype) if cfg.is_mla
+                 else attn.attn_init(ks[1], cfg, dtype))
     p["norm2"] = jnp.ones((cfg.d_model,), dtype)
     if fam == "hybrid":
         p["ssm"] = ssm.ssm_init(ks[0], cfg, dtype)
@@ -76,7 +102,7 @@ def block_init(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
         p["mlp"] = layers.mlp_init(ks[2], cfg.d_model, cfg.d_ff, "swiglu",
                                    dtype)
         return p
-    if cfg.is_moe:
+    if _moe_mlp(cfg, dense_mlp):
         p["moe"] = moe.moe_init(ks[3], cfg, dtype)
     else:
         kind = "gelu" if cfg.is_encoder_decoder else "swiglu"
@@ -84,13 +110,13 @@ def block_init(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
     return p
 
 
-def block_axes(cfg: ModelConfig) -> Dict[str, Any]:
+def block_axes(cfg: ModelConfig, dense_mlp: bool = False) -> Dict[str, Any]:
     a: Dict[str, Any] = {"norm1": ("embed_act",)}
     fam = cfg.family
     if fam == "ssm":
         a["ssm"] = ssm.ssm_axes(cfg)
         return a
-    a["attn"] = attn.attn_axes(cfg)
+    a["attn"] = attn.mla_axes(cfg) if cfg.is_mla else attn.attn_axes(cfg)
     a["norm2"] = ("embed_act",)
     if fam == "hybrid":
         a["ssm"] = ssm.ssm_axes(cfg)
@@ -98,8 +124,8 @@ def block_axes(cfg: ModelConfig) -> Dict[str, Any]:
         a["branch_norm_ssm"] = ("embed_act",)
         a["mlp"] = layers.mlp_axes("swiglu")
         return a
-    if cfg.is_moe:
-        a["moe"] = moe.moe_axes()
+    if _moe_mlp(cfg, dense_mlp):
+        a["moe"] = moe.moe_axes(cfg)
     else:
         kind = "gelu" if cfg.is_encoder_decoder else "swiglu"
         a["mlp"] = layers.mlp_axes(kind)
@@ -110,8 +136,8 @@ def block_axes(cfg: ModelConfig) -> Dict[str, Any]:
 # Forward (train / full-sequence)
 # ---------------------------------------------------------------------------
 
-def block_apply(p, x, cfg: ModelConfig, ctx: ModelCtx, window
-                ) -> Tuple[jax.Array, jax.Array]:
+def block_apply(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
+                dense_mlp: bool = False) -> Tuple[jax.Array, jax.Array]:
     """x [B,S,d] -> (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     fam = cfg.family
@@ -136,20 +162,16 @@ def block_apply(p, x, cfg: ModelConfig, ctx: ModelCtx, window
                         "batch", "seq", "embed_act")
         return x, aux
     # dense / moe / vlm decoder layer
-    x = x + ctx.act(
-        attn.attn_apply(p["attn"], h, cfg, window=window, impl=ctx.attn_impl,
-                        mesh=ctx.mesh, tp_axis=ctx.tp_axis,
-                        batch_axes=ctx.batch_axes),
-        "batch", "seq", "embed_act")
-    h2 = layers.rmsnorm(x, p["norm2"], cfg.norm_eps, ctx.norm_impl)
-    if cfg.is_moe:
-        y, aux = moe.moe_apply(p["moe"], h2, cfg, impl=ctx.moe_impl,
-                               mesh=ctx.mesh, tp_axis=ctx.tp_axis,
-                               batch_axes=ctx.batch_axes,
-                               gmm_impl=ctx.gmm_impl)
+    if cfg.is_mla:
+        a, _ = attn.mla_prefill(p["attn"], h, cfg, impl=ctx.attn_impl,
+                                mesh=ctx.mesh)
     else:
-        kind = "gelu" if cfg.is_encoder_decoder else "swiglu"
-        y = layers.mlp_apply(p["mlp"], h2, kind)
+        a = attn.attn_apply(p["attn"], h, cfg, window=window,
+                            impl=ctx.attn_impl, mesh=ctx.mesh,
+                            tp_axis=ctx.tp_axis, batch_axes=ctx.batch_axes)
+    x = x + ctx.act(a, "batch", "seq", "embed_act")
+    h2 = layers.rmsnorm(x, p["norm2"], cfg.norm_eps, ctx.norm_impl)
+    y, aux, _ = _mlp(p, h2, cfg, ctx, dense_mlp)
     x = x + ctx.act(y, "batch", "seq", "embed_act")
     return x, aux
 
@@ -159,42 +181,53 @@ def block_apply(p, x, cfg: ModelConfig, ctx: ModelCtx, window
 # ---------------------------------------------------------------------------
 
 def init_layer_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype,
-                     kv_dtype) -> LayerCache:
+                     kv_dtype, dense_mlp: bool = False) -> LayerCache:
     fam = cfg.family
     kv = (attn.init_kv_cache(cfg, batch, max_seq, kv_dtype)
-          if fam != "ssm" else _empty_kv())
+          if fam != "ssm" and not cfg.is_mla else _empty_kv())
     st = (ssm.init_ssm_cache(cfg, batch, dtype)
           if fam in ("ssm", "hybrid") else _empty_ssm())
-    return LayerCache(kv=kv, ssm=st)
+    lat = (attn.init_latent_cache(cfg, batch, max_seq, kv_dtype)
+           if cfg.is_mla else _empty())
+    load = (jnp.zeros((cfg.num_experts,), jnp.int32)
+            if _moe_mlp(cfg, dense_mlp) else _empty())
+    return LayerCache(kv=kv, ssm=st, latent=lat, expert_load=load)
 
 
-def cache_axes(cfg: ModelConfig) -> LayerCache:
+def cache_axes(cfg: ModelConfig, dense_mlp: bool = False) -> LayerCache:
     fam = cfg.family
-    kv = attn.kv_cache_axes() if fam != "ssm" else attn.KVLayerCache(
-        (None,), (None,))
+    kv = attn.kv_cache_axes() if fam != "ssm" and not cfg.is_mla else \
+        attn.KVLayerCache((None,), (None,))
     st = ssm.ssm_cache_axes() if fam in ("ssm", "hybrid") else \
         ssm.SSMLayerCache((None,), (None,))
-    return LayerCache(kv=kv, ssm=st)
+    lat = ("batch", None, "kv_seq") if cfg.is_mla else (None,)
+    return LayerCache(kv=kv, ssm=st, latent=lat, expert_load=(None,))
 
 
 def block_prefill(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
-                  cache: LayerCache) -> Tuple[jax.Array, LayerCache]:
+                  cache: LayerCache, dense_mlp: bool = False
+                  ) -> Tuple[jax.Array, LayerCache]:
     """Full-sequence forward that also fills the decode cache.
 
-    The KV cache slots [0:S] are written; the SSM state comes from the
-    chunked scan's final state.
+    The KV (or latent) cache slots [0:S] are written; the SSM state
+    comes from the chunked scan's final state.
     """
     fam = cfg.family
     B, S, d = x.shape
     h = layers.rmsnorm(x, p["norm1"], cfg.norm_eps, ctx.norm_impl)
-    aux0 = jnp.zeros((), jnp.float32)
 
-    new_kv, new_ssm = cache.kv, cache.ssm
+    new_kv, new_ssm, new_lat = cache.kv, cache.ssm, cache.latent
 
     if fam in ("ssm", "hybrid"):
         s_out, new_ssm = ssm.ssm_prefill(p["ssm"], h, cfg, impl=ctx.ssd_impl)
 
-    if fam != "ssm":
+    if cfg.is_mla:
+        a_out, lat = attn.mla_prefill(p["attn"], h, cfg, impl=ctx.attn_impl,
+                                      mesh=ctx.mesh)
+        new_lat = jax.lax.dynamic_update_slice_in_dim(
+            cache.latent, jnp.swapaxes(lat, 1, 2).astype(cache.latent.dtype),
+            0, axis=2)
+    elif fam != "ssm":
         with jax.named_scope("attention"):
             positions = jnp.arange(S)
             q, k, v = attn._project_qkv(p["attn"], h, cfg, positions)
@@ -209,8 +242,19 @@ def block_prefill(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
                                   prefix=cfg.n_meta_tokens, mesh=ctx.mesh)
             a_out = jnp.einsum("bhsk,hkd->bsd", a_out, p["attn"]["wo"])
 
+    return _finish_block(p, x, a_out if fam != "ssm" else None,
+                         s_out if fam in ("ssm", "hybrid") else None, cfg,
+                         ctx, cache, new_kv, new_ssm, new_lat, dense_mlp)
+
+
+def _finish_block(p, x, a_out, s_out, cfg: ModelConfig, ctx: ModelCtx,
+                  cache: LayerCache, new_kv, new_ssm, new_lat,
+                  dense_mlp: bool) -> Tuple[jax.Array, LayerCache]:
+    """The residual adds and the MLP after a prefill or decode step's
+    mixers, with the new cache."""
+    fam = cfg.family
     if fam == "ssm":
-        return x + s_out, LayerCache(new_kv, new_ssm)
+        return x + s_out, cache._replace(kv=new_kv, ssm=new_ssm)
     if fam == "hybrid":
         mix = (layers.rmsnorm(a_out, p["branch_norm_attn"], cfg.norm_eps,
                               ctx.norm_impl)
@@ -219,29 +263,27 @@ def block_prefill(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
         x = x + mix
         h2 = layers.rmsnorm(x, p["norm2"], cfg.norm_eps, ctx.norm_impl)
         x = x + layers.mlp_apply(p["mlp"], h2, "swiglu")
-        return x, LayerCache(new_kv, new_ssm)
+        return x, cache._replace(kv=new_kv, ssm=new_ssm)
     x = x + a_out
     h2 = layers.rmsnorm(x, p["norm2"], cfg.norm_eps, ctx.norm_impl)
-    if cfg.is_moe:
-        y, _ = moe.moe_apply(p["moe"], h2, cfg, impl=ctx.moe_impl,
-                             mesh=ctx.mesh, tp_axis=ctx.tp_axis,
-                             batch_axes=ctx.batch_axes, gmm_impl=ctx.gmm_impl)
-    else:
-        kind = "gelu" if cfg.is_encoder_decoder else "swiglu"
-        y = layers.mlp_apply(p["mlp"], h2, kind)
-    return x + y, LayerCache(new_kv, new_ssm)
+    y, _, load = _mlp(p, h2, cfg, ctx, dense_mlp)
+    return x + y, LayerCache(new_kv, new_ssm, new_lat,
+                             load.astype(cache.expert_load.dtype))
 
 
 def block_decode(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
-                 cache: LayerCache, pos) -> Tuple[jax.Array, LayerCache]:
+                 cache: LayerCache, pos, dense_mlp: bool = False
+                 ) -> Tuple[jax.Array, LayerCache]:
     """One-token step. x [B,1,d]."""
     fam = cfg.family
     h = layers.rmsnorm(x, p["norm1"], cfg.norm_eps, ctx.norm_impl)
-    new_kv, new_ssm = cache.kv, cache.ssm
+    new_kv, new_ssm, new_lat = cache.kv, cache.ssm, cache.latent
 
     if fam in ("ssm", "hybrid"):
         s_out, new_ssm = ssm.ssm_decode(p["ssm"], h, cache.ssm, cfg)
-    if fam != "ssm":
+    if cfg.is_mla:
+        a_out, new_lat = attn.mla_decode(p["attn"], h, cache.latent, pos, cfg)
+    elif fam != "ssm":
         if ctx.decode_attn_impl == "seqshard":
             a_out, new_kv = attn.attn_decode_seqshard(
                 p["attn"], h, cache.kv, pos, cfg, ctx.mesh,
@@ -251,27 +293,9 @@ def block_decode(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
                 p["attn"], h, cache.kv, pos, cfg, window=window,
                 impl=ctx.decode_attn_impl, prefix=cfg.n_meta_tokens)
 
-    if fam == "ssm":
-        return x + s_out, LayerCache(new_kv, new_ssm)
-    if fam == "hybrid":
-        mix = (layers.rmsnorm(a_out, p["branch_norm_attn"], cfg.norm_eps,
-                              ctx.norm_impl)
-               + layers.rmsnorm(s_out, p["branch_norm_ssm"], cfg.norm_eps,
-                                ctx.norm_impl)) * 0.5
-        x = x + mix
-        h2 = layers.rmsnorm(x, p["norm2"], cfg.norm_eps, ctx.norm_impl)
-        return x + layers.mlp_apply(p["mlp"], h2, "swiglu"), \
-            LayerCache(new_kv, new_ssm)
-    x = x + a_out
-    h2 = layers.rmsnorm(x, p["norm2"], cfg.norm_eps, ctx.norm_impl)
-    if cfg.is_moe:
-        y, _ = moe.moe_apply(p["moe"], h2, cfg, impl=ctx.moe_impl,
-                             mesh=ctx.mesh, tp_axis=ctx.tp_axis,
-                             batch_axes=ctx.batch_axes, gmm_impl=ctx.gmm_impl)
-    else:
-        kind = "gelu" if cfg.is_encoder_decoder else "swiglu"
-        y = layers.mlp_apply(p["mlp"], h2, kind)
-    return x + y, LayerCache(new_kv, new_ssm)
+    return _finish_block(p, x, a_out if fam != "ssm" else None,
+                         s_out if fam in ("ssm", "hybrid") else None, cfg,
+                         ctx, cache, new_kv, new_ssm, new_lat, dense_mlp)
 
 
 def layer_windows(cfg: ModelConfig) -> jax.Array:

@@ -110,6 +110,8 @@ def embed_lookup(table: jax.Array, ids: jax.Array, d: int) -> jax.Array:
 
 
 def unembed(x: jax.Array, table: jax.Array) -> jax.Array:
-    """x [..., d] @ table^T [V, d] -> logits fp32."""
-    return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
-                      table.astype(jnp.float32))
+    """x [..., d] @ table^T [V, d] -> logits fp32, the operands in the
+    wider of their two dtypes (a bf16 table is never copied to f32)."""
+    dt = jnp.promote_types(x.dtype, table.dtype)
+    return jnp.einsum("...d,vd->...v", x.astype(dt), table.astype(dt),
+                      preferred_element_type=jnp.float32)

@@ -2,7 +2,10 @@
 
 Covers the dense / moe / ssm / hybrid / vlm families. Layers are stacked
 along a leading "layers" dim so the HLO is depth-independent; remat
-policy wraps the scanned body. Parameters are stored in
+policy wraps the scanned body. A MoE model's leading dense layers
+(``first_k_dense``), whose MLP has another shape, are a stack of their
+own (params ``dense_layers``) scanned before the main one; the decode
+cache is then a tuple of the two stacks' caches. Parameters are stored in
 ``cfg.param_dtype`` and cast to ``cfg.dtype`` per layer inside the scan
 (the cast fuses into the layer compute — no full low-precision copy is
 ever materialized).
@@ -10,7 +13,7 @@ ever materialized).
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,10 +33,26 @@ def _remat(fn, policy: str):
     return jax.checkpoint(fn)   # "full": save only layer inputs
 
 
+# kept in float32 whatever the compute dtype: the router's scores and
+# its score-correction bias choose the experts
+_KEEP_F32 = ("router", "router_bias")
+
+
 def _cast(tree, dtype):
+    def cast(path, a):
+        if not jnp.issubdtype(a.dtype, jnp.floating) or \
+                (path and getattr(path[-1], "key", None) in _KEEP_F32):
+            return a
+        return a.astype(dtype)
+    return jax.tree_util.tree_map_with_path(cast, tree)
+
+
+def _stacked(axes_tree):
+    """Per-layer logical axes with the leading "layers" axis added."""
     return jax.tree.map(
-        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
-        else a, tree)
+        lambda axes: ("layers",) + axes, axes_tree,
+        is_leaf=lambda x: isinstance(x, tuple)
+        and all(isinstance(e, (str, type(None))) for e in x))
 
 
 class LM:
@@ -50,10 +69,11 @@ class LM:
         p: Dict[str, Any] = {
             "embed": layers.embed_init(k_embed, cfg.padded_vocab(), cfg.d_model,
                                        dtype),
-            "layers": jax.vmap(
-                lambda k: blocks.block_init(k, cfg, dtype))(layer_keys),
             "final_norm": jnp.ones((cfg.d_model,), dtype),
         }
+        for key, lo, n, dense in self._segments():
+            p[key] = jax.vmap(lambda k: blocks.block_init(
+                k, cfg, dtype, dense))(layer_keys[lo:lo + n])
         if not cfg.tie_embeddings:
             p["unembed"] = layers.embed_init(k_un, cfg.padded_vocab(),
                                              cfg.d_model, dtype)
@@ -65,16 +85,12 @@ class LM:
 
     def param_axes(self) -> Dict[str, Any]:
         cfg = self.cfg
-        per_layer = blocks.block_axes(cfg)
-        stacked = jax.tree.map(
-            lambda axes: ("layers",) + axes, per_layer,
-            is_leaf=lambda x: isinstance(x, tuple)
-            and all(isinstance(e, (str, type(None))) for e in x))
         a: Dict[str, Any] = {
             "embed": ("vocab", "embed"),
-            "layers": stacked,
             "final_norm": ("embed_act",),
         }
+        for key, _, _, dense in self._segments():
+            a[key] = _stacked(blocks.block_axes(cfg, dense))
         if not cfg.tie_embeddings:
             a["unembed"] = ("vocab", "embed")
         if cfg.n_meta_tokens:
@@ -104,6 +120,27 @@ class LM:
         windows = blocks.layer_windows(cfg)
         return uw, windows
 
+    def _segments(self) -> List[Tuple[str, int, int, bool]]:
+        """(params key, first layer, layer count, dense MLP) of each stack
+        of like layers, in order."""
+        cfg = self.cfg
+        k = cfg.first_k_dense if cfg.is_moe else 0
+        head = [("dense_layers", 0, k, True)] if k else []
+        return head + [("layers", k, cfg.num_layers - k, False)]
+
+    def _split_cache(self, cache) -> List[LayerCache]:
+        return list(cache) if len(self._segments()) > 1 else [cache]
+
+    def _join_cache(self, caches: List[LayerCache]):
+        return tuple(caches) if len(caches) > 1 else caches[0]
+
+    def expert_load(self, cache) -> Optional[jax.Array]:
+        """Tokens the step that wrote ``cache`` routed to each expert,
+        [MoE layers, E] int32; None for a model without experts."""
+        loads = [c.expert_load for c in self._split_cache(cache)
+                 if c.expert_load.size]
+        return loads[0] if loads else None
+
     # --------------------------------------------------------------- forward
     def forward(self, p, tokens: jax.Array, ctx: ModelCtx
                 ) -> Tuple[jax.Array, jax.Array]:
@@ -112,39 +149,42 @@ class LM:
         x = self._embed_tokens(p, tokens, ctx)
         uw, windows = self._layer_inputs()
 
-        def layer_fn(x, xs):
-            p_l, w = xs
-            p_l = _cast(p_l, cfg.dtype)
-            x, aux = blocks.block_apply(p_l, x, cfg, ctx,
-                                        uw if uw is not None else w)
-            return x, aux
+        aux = jnp.zeros((), jnp.float32)
+        for key, lo, n, dense in self._segments():
+            def layer_fn(x, xs, dense=dense):
+                p_l, w = xs
+                p_l = _cast(p_l, cfg.dtype)
+                return blocks.block_apply(p_l, x, cfg, ctx,
+                                          uw if uw is not None else w, dense)
 
-        body = _remat(layer_fn, ctx.remat_policy)
-        x, auxs = jax.lax.scan(body, x, (p["layers"], windows))
+            body = _remat(layer_fn, ctx.remat_policy)
+            x, auxs = jax.lax.scan(body, x, (p[key], windows[lo:lo + n]))
+            aux = aux + auxs.sum()
         x = layers.rmsnorm(x, _cast(p["final_norm"], cfg.dtype), cfg.norm_eps,
                            ctx.norm_impl)
         if cfg.n_meta_tokens:
             x = x[:, cfg.n_meta_tokens:]
         logits = self._unembed(p, x)
-        return ctx.act(logits, "batch", "seq", "vocab"), auxs.sum()
+        return ctx.act(logits, "batch", "seq", "vocab"), aux
 
     # ----------------------------------------------------------- serve paths
-    def init_cache(self, batch: int, max_seq: int, ctx: ModelCtx
-                   ) -> LayerCache:
+    def init_cache(self, batch: int, max_seq: int, ctx: ModelCtx):
+        """A LayerCache stacked over the layers (a tuple of one per stack
+        where the model has leading dense layers)."""
         cfg = self.cfg
-        template = blocks.init_layer_cache(
-            cfg, batch, max_seq + cfg.n_meta_tokens, jnp.dtype(cfg.dtype),
-            jnp.dtype(cfg.dtype))
-        return jax.tree.map(
-            lambda a: jnp.zeros((cfg.num_layers,) + a.shape, a.dtype),
-            template)
+        caches = []
+        for _, _, n, dense in self._segments():
+            template = blocks.init_layer_cache(
+                cfg, batch, max_seq + cfg.n_meta_tokens, jnp.dtype(cfg.dtype),
+                jnp.dtype(cfg.dtype), dense)
+            caches.append(jax.tree.map(
+                lambda a, n=n: jnp.zeros((n,) + a.shape, a.dtype), template))
+        return self._join_cache(caches)
 
-    def cache_axes(self) -> LayerCache:
-        per_layer = blocks.cache_axes(self.cfg)
-        return jax.tree.map(
-            lambda axes: ("layers",) + axes, per_layer,
-            is_leaf=lambda x: isinstance(x, tuple)
-            and all(isinstance(e, (str, type(None))) for e in x))
+    def cache_axes(self):
+        return self._join_cache([
+            _stacked(blocks.cache_axes(self.cfg, dense))
+            for _, _, _, dense in self._segments()])
 
     def prefill(self, p, tokens: jax.Array, cache: LayerCache, ctx: ModelCtx
                 ) -> Tuple[jax.Array, LayerCache, jax.Array]:
@@ -154,20 +194,24 @@ class LM:
         x = self._embed_tokens(p, tokens, ctx)
         uw, windows = self._layer_inputs()
 
-        def layer_fn(x, xs):
-            p_l, w, cache_l = xs
-            p_l = _cast(p_l, cfg.dtype)
-            x, new_cache = blocks.block_prefill(
-                p_l, x, cfg, ctx, uw if uw is not None else w, cache_l)
-            return x, new_cache
+        new = []
+        for (key, lo, n, dense), cache_s in zip(self._segments(),
+                                                self._split_cache(cache)):
+            def layer_fn(x, xs, dense=dense):
+                p_l, w, cache_l = xs
+                p_l = _cast(p_l, cfg.dtype)
+                return blocks.block_prefill(
+                    p_l, x, cfg, ctx, uw if uw is not None else w, cache_l,
+                    dense)
 
-        x, new_cache = jax.lax.scan(layer_fn, x,
-                                    (p["layers"], windows, cache))
+            x, cache_s = jax.lax.scan(layer_fn, x,
+                                      (p[key], windows[lo:lo + n], cache_s))
+            new.append(cache_s)
         x = layers.rmsnorm(x, _cast(p["final_norm"], cfg.dtype), cfg.norm_eps,
                            ctx.norm_impl)
         logits = self._unembed(p, x[:, -1])
         pos = jnp.asarray(tokens.shape[1] + cfg.n_meta_tokens, jnp.int32)
-        return logits, new_cache, pos
+        return logits, self._join_cache(new), pos
 
     def decode_step(self, p, token: jax.Array, cache: LayerCache,
                     pos: jax.Array, ctx: ModelCtx
@@ -179,17 +223,22 @@ class LM:
         x = x.astype(cfg.dtype)
         uw, windows = self._layer_inputs()
 
-        def layer_fn(carry, xs):
-            x, pos = carry
-            p_l, w, cache_l = xs
-            p_l = _cast(p_l, cfg.dtype)
-            x, new_cache = blocks.block_decode(
-                p_l, x, cfg, ctx, uw if uw is not None else w, cache_l, pos)
-            return (x, pos), new_cache
+        new = []
+        for (key, lo, n, dense), cache_s in zip(self._segments(),
+                                                self._split_cache(cache)):
+            def layer_fn(carry, xs, dense=dense):
+                x, pos = carry
+                p_l, w, cache_l = xs
+                p_l = _cast(p_l, cfg.dtype)
+                x, new_cache = blocks.block_decode(
+                    p_l, x, cfg, ctx, uw if uw is not None else w, cache_l,
+                    pos, dense)
+                return (x, pos), new_cache
 
-        (x, _), new_cache = jax.lax.scan(layer_fn, (x, pos),
-                                         (p["layers"], windows, cache))
+            (x, _), cache_s = jax.lax.scan(
+                layer_fn, (x, pos), (p[key], windows[lo:lo + n], cache_s))
+            new.append(cache_s)
         x = layers.rmsnorm(x, _cast(p["final_norm"], cfg.dtype), cfg.norm_eps,
                            ctx.norm_impl)
         logits = self._unembed(p, x[:, 0])
-        return logits, new_cache
+        return logits, self._join_cache(new)

@@ -22,8 +22,17 @@
   their local experts on the (small) replicated token set and psum the
   gate-weighted partial outputs — no all_to_all at trivial token counts.
 
-Router runs in fp32; an auxiliary load-balance loss (Switch-style) is
-returned alongside.
+* ``dropless`` — one device holding every expert: tokens sorted by
+  expert and each expert's rows multiplied as one group of a ragged
+  grouped matmul (``kernels.ops.ragged_gmm``: megablox on TPU,
+  ``lax.ragged_dot`` elsewhere). Nothing is padded to a capacity and
+  nothing is dropped.
+
+Router runs in fp32, by softmax or (DeepSeek-V3 ``noaux_tc``) by sigmoid
+scores whose choice adds a score-correction bias that the gates leave
+out; an auxiliary load-balance loss (Switch-style) is returned
+alongside. Shared experts (one SwiGLU MLP) add to every token's output.
+``moe_apply`` also returns the step's tokens per expert.
 """
 from __future__ import annotations
 
@@ -41,30 +50,55 @@ from repro.models import layers
 
 def moe_init(key, cfg: ModelConfig, dtype) -> Dict[str, jax.Array]:
     d, E, ff = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
-    ks = jax.random.split(key, 4)
-    return {
+    ks = jax.random.split(key, 5)
+    p = {
         "router": layers.trunc_normal(ks[0], (d, E), d ** -0.5, jnp.float32),
         "w_gate": layers.trunc_normal(ks[1], (E, d, ff), d ** -0.5, dtype),
         "w_up": layers.trunc_normal(ks[2], (E, d, ff), d ** -0.5, dtype),
         "w_down": layers.trunc_normal(ks[3], (E, ff, d), ff ** -0.5, dtype),
     }
+    if cfg.router_scoring == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
+    if cfg.n_shared_experts:
+        p["shared"] = layers.mlp_init(ks[4], d, cfg.n_shared_experts * ff,
+                                      "swiglu", dtype)
+    return p
 
 
-def moe_axes() -> Dict[str, Tuple[str, ...]]:
-    return {
+def moe_axes(cfg: ModelConfig) -> Dict[str, Any]:
+    a: Dict[str, Any] = {
         "router": ("embed", None),
         "w_gate": ("experts", "embed", "expert_ffn"),
         "w_up": ("experts", "embed", "expert_ffn"),
         "w_down": ("experts", "expert_ffn", "embed"),
     }
+    if cfg.router_scoring == "sigmoid":
+        a["router_bias"] = (None,)
+    if cfg.n_shared_experts:
+        a["shared"] = layers.mlp_axes("swiglu")
+    return a
 
 
+def _router_params(p) -> Dict[str, jax.Array]:
+    return {k: p[k] for k in ("router", "router_bias") if k in p}
+
+
+@jax.named_scope("moe_router")
 def _route(p, x, cfg: ModelConfig):
     """x [..., d] -> (topk_gates [..., k], topk_idx [..., k], aux_loss)."""
-    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, cfg.experts_per_token)
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + p["router_bias"],
+                               cfg.experts_per_token)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, cfg.experts_per_token)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates = gates * cfg.routed_scaling_factor
     # Switch-style load-balance aux loss
     E = cfg.num_experts
     me = probs.mean(axis=tuple(range(probs.ndim - 1)))          # [E]
@@ -144,8 +178,7 @@ def _moe_ep_local(x_l, router, w_gate, w_up, w_down, *, cfg: ModelConfig,
     E_l = w_gate.shape[0]
     x_f = x_l.reshape(-1, d)
     T = x_f.shape[0]
-    p = {"router": router}
-    gates, idx, aux = _route(p, x_f, cfg)
+    gates, idx, aux = _route(router, x_f, cfg)
     C = _capacity(T, cfg, n_shards)
 
     buffers, inv = _local_group(x_f, gates, idx, E, C)       # [E, C, d]
@@ -187,7 +220,7 @@ def _moe_decode_local(x_l, router, w_gate, w_up, w_down, *, cfg: ModelConfig,
     E_l = w_gate.shape[0]
     x_f = x_l.reshape(-1, d)
     T = x_f.shape[0]
-    gates, idx, aux = _route({"router": router}, x_f, cfg)
+    gates, idx, aux = _route(router, x_f, cfg)
     # mask for choices owned by this rank
     local = (idx >= shard_id * E_l) & (idx < (shard_id + 1) * E_l)
     local_idx = jnp.where(local, idx - shard_id * E_l, 0)
@@ -219,7 +252,7 @@ def moe_apply_ep(p, x, cfg: ModelConfig, mesh, *, tp_axis: str = "model",
 
     if mesh is None:
         y, aux = _moe_ep_local(
-            x, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+            x, _router_params(p), p["w_gate"], p["w_up"], p["w_down"],
             cfg=cfg, axis=tp_axis, n_shards=1, gmm_impl=gmm_impl)
         return y, aux
 
@@ -249,18 +282,63 @@ def moe_apply_ep(p, x, cfg: ModelConfig, mesh, *, tp_axis: str = "model",
     w_spec = P(tp_axis, None, None)                 # experts live on TP ranks
     out = shard_map(
         body, mesh=mesh,
-        in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
+        in_specs=(x_spec, P(), w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),
         check_vma=False,
-    )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
+    )(x, _router_params(p), p["w_gate"], p["w_up"], p["w_down"])
     return out
+
+
+# ---------------------------------------------------------------------------
+# Dropless path (every expert on one device)
+# ---------------------------------------------------------------------------
+
+def moe_apply_dropless(p, x, cfg: ModelConfig, *, gmm_impl: str = "auto"
+                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Sort the (token, choice) rows by expert and run each expert's rows
+    as one group of a ragged grouped matmul. x [B,S,d] -> (y, aux,
+    tokens per expert [E])."""
+    B, S, d = x.shape
+    E, k = cfg.num_experts, cfg.experts_per_token
+    xf = x.reshape(-1, d)
+    gates, idx, aux = _route(p, xf, cfg)
+    with jax.named_scope("moe_experts"):
+        flat = idx.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        rows = xf[order // k]                                  # [T*k, d]
+        g = ops.ragged_gmm(rows, p["w_gate"], sizes, impl=gmm_impl)
+        u = ops.ragged_gmm(rows, p["w_up"], sizes, impl=gmm_impl)
+        h = (jax.nn.silu(g.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(x.dtype)
+        y = ops.ragged_gmm(h, p["w_down"], sizes, impl=gmm_impl)
+        y = jnp.zeros_like(y).at[order].set(y)                 # unsort
+        out = (y.reshape(-1, k, d).astype(jnp.float32)
+               * gates[..., None]).sum(axis=1)
+    return out.reshape(B, S, d).astype(x.dtype), aux, sizes
+
+
+def expert_load(idx, E: int) -> jax.Array:
+    """Tokens routed to each expert: [E] int32."""
+    return jnp.bincount(idx.reshape(-1), length=E).astype(jnp.int32)
 
 
 @jax.named_scope("mlp")
 def moe_apply(p, x, cfg: ModelConfig, *, impl: str = "ep", mesh=None,
               tp_axis: str = "model", batch_axes=("pod", "data"),
-              gmm_impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
-    if impl == "dense":
-        return moe_apply_dense(p, x, cfg)
-    return moe_apply_ep(p, x, cfg, mesh, tp_axis=tp_axis,
-                        batch_axes=batch_axes, gmm_impl=gmm_impl)
+              gmm_impl: str = "auto"
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x [B,S,d] -> (y, aux loss, tokens routed to each expert [E])."""
+    if impl == "dropless":
+        y, aux, load = moe_apply_dropless(p, x, cfg, gmm_impl=gmm_impl)
+    else:
+        if impl == "dense":
+            y, aux = moe_apply_dense(p, x, cfg)
+        else:
+            y, aux = moe_apply_ep(p, x, cfg, mesh, tp_axis=tp_axis,
+                                  batch_axes=batch_axes, gmm_impl=gmm_impl)
+        load = expert_load(_route(p, x, cfg)[1], cfg.num_experts)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            y = y + layers.mlp_apply(p["shared"], x, "swiglu")
+    return y, aux, load

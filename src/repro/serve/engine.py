@@ -1,12 +1,17 @@
 """Batched prefill + decode engine.
 
 ``serve_step`` (one token for the whole batch against the KV cache) is
-the unit the decode-shape dry-runs lower. The sampler — logits [B,V] +
-key -> token ids [B] — is an active-code slot: an analyst can deploy a
-new sampling rule (temperature change, top-k, logit bias) between decode
-steps of an *ongoing* generation, the serving analogue of the paper's
-mid-assignment algorithm swap. Executables are cached per sampler
-fingerprint exactly like the train step.
+the unit the decode-shape dry-runs lower. ``generate`` is ``prefill``
+then the decode loop; ``decode`` runs the same loop from a cache and a
+position the caller keeps, so that further turns reuse a resident,
+prefilled cache (``prefill`` can fill a chunk of its rows in place).
+
+The sampler — logits [B,V] + key -> token ids [B] — is an active-code
+slot: an analyst can deploy a new sampling rule (temperature change,
+top-k, logit bias) between decode steps of an *ongoing* generation, the
+serving analogue of the paper's mid-assignment algorithm swap.
+Executables are cached per sampler fingerprint exactly like the train
+step.
 """
 from __future__ import annotations
 
@@ -18,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import RunConfig
 from repro.core.registry import Binding, LocalDeployment
-from repro.core.telemetry import Metrics, rebuild_span, timed
+from repro.core.telemetry import Metrics, StepArrays, rebuild_span, timed
 from repro.core.tracing import SpanRecorder
 from repro.models.blocks import ModelCtx
 from repro.train.step import build_ctx
@@ -55,7 +60,8 @@ class ServeEngine:
     """``metrics`` holds the ``serve.*`` counters and span histograms;
     ``spans`` keeps one ``serve.rebuild`` record per decode step built,
     with the sampler's md5 and its trace, lower and backend-compile
-    seconds."""
+    seconds; ``expert_load``, while on, keeps each decode step's tokens
+    routed to each expert ([MoE layers, E], a MoE model only)."""
 
     def __init__(self, model, cfg: RunConfig, *,
                  sampler_binding: Optional[Binding] = None,
@@ -68,8 +74,10 @@ class ServeEngine:
         self.max_seq = max_seq or cfg.shape.seq_len
         self._cache: Dict[Tuple, Callable] = {}
         self._prefill_jit = None
+        self._fill_jit = None
         self.metrics = Metrics()
         self.spans = SpanRecorder("serve")
+        self.expert_load = StepArrays()
 
     @property
     def rebuilds(self) -> int:
@@ -112,7 +120,13 @@ class ServeEngine:
 
     # ------------------------------------------------------------------
     def prefill(self, params, prompt: jax.Array,
-                frames: Optional[jax.Array] = None):
+                frames: Optional[jax.Array] = None, *, cache=None,
+                row: int = 0):
+        """(last-position logits, cache, next position) of ``prompt``
+        [B, S]. Without ``cache`` into a new cache of B rows; with one
+        (donated), into its rows [row, row + B), the rest kept."""
+        if cache is not None:
+            return self._fill(params, prompt, cache, row)
         if self._prefill_jit is None:
             model, ctx = self.model, self.ctx
             if model.cfg.is_encoder_decoder:
@@ -129,26 +143,78 @@ class ServeEngine:
                 return self._prefill_jit(params, prompt, frames, cache)
             return self._prefill_jit(params, prompt, cache)
 
+    def fill_program(self):
+        """The jitted prefill of a chunk of rows into a larger cache (the
+        cache donated): the rows are sliced out along each leaf's batch
+        axis, prefilled and written back, in one program."""
+        if self._fill_jit is None:
+            model, ctx = self.model, self.ctx
+            is_axes = (lambda x: isinstance(x, tuple) and all(
+                isinstance(e, (str, type(None))) for e in x))
+            bax = [a.index("batch") if "batch" in a else None
+                   for a in jax.tree.leaves(model.cache_axes(),
+                                            is_leaf=is_axes)]
+
+            def fill(p, t, c, row):
+                leaves, tree = jax.tree.flatten(c)
+                sub = [x if b is None else jax.lax.dynamic_slice_in_dim(
+                    x, row, t.shape[0], axis=b) for x, b in zip(leaves, bax)]
+                logits, sub, pos = model.prefill(
+                    p, t, jax.tree.unflatten(tree, sub), ctx)
+                out = [s if b is None else jax.lax.dynamic_update_slice_in_dim(
+                    x, s, row, axis=b)
+                    for x, s, b in zip(leaves, jax.tree.leaves(sub), bax)]
+                return logits, jax.tree.unflatten(tree, out), pos
+            self._fill_jit = jax.jit(fill, donate_argnums=(2,))
+        return self._fill_jit
+
+    def _fill(self, params, prompt, cache, row):
+        fill = self.fill_program()
+        with timed(self.metrics, "serve.prefill"):
+            return fill(params, prompt, cache, jnp.asarray(row, jnp.int32))
+
     def generate(self, params, prompt: jax.Array, n_tokens: int, *,
                  frames: Optional[jax.Array] = None, seed: int = 0,
                  on_token: Optional[Callable[[int, jax.Array], None]] = None
                  ) -> Tuple[jax.Array, Dict[str, Any]]:
-        """Decode loop with per-token sampler rebinding (hot-swap point)."""
+        """Prefill, then the decode loop with per-token sampler rebinding
+        (hot-swap point)."""
         logits, cache, pos = self.prefill(params, prompt, frames=frames)
         key = jax.random.PRNGKey(seed)
         fp, sampler, md5 = self._resolve_sampler()
         with timed(self.metrics, "serve.first_token"), \
                 jax.named_scope("sampler"):
             tok = sampler(logits, key).astype(jnp.int32)
-        out = [tok]
-        md5s = [md5]
-        for i in range(n_tokens - 1):
+        out, md5s, *_ = self._loop(params, tok, cache, pos, key,
+                                   n_tokens - 1, on_token)
+        return jnp.stack([tok] + out, axis=1), {
+            "sampler_md5s": [md5] + md5s, "rebuilds": self.rebuilds}
+
+    def decode(self, params, token: jax.Array, cache, pos, n_tokens: int, *,
+               seed: int = 0,
+               on_token: Optional[Callable[[int, jax.Array], None]] = None):
+        """``n_tokens`` steps of the decode loop from ``token`` [B] at
+        position ``pos`` in ``cache`` (donated): each step writes the
+        token's row at its position and samples the next token. Returns
+        (tokens [B, n_tokens], cache, next position, info)."""
+        out, md5s, cache, pos = self._loop(
+            params, token, cache, jnp.asarray(pos, jnp.int32),
+            jax.random.PRNGKey(seed), n_tokens, on_token)
+        return jnp.stack(out, axis=1), cache, pos, {
+            "sampler_md5s": md5s, "rebuilds": self.rebuilds}
+
+    def _loop(self, params, tok, cache, pos, key, n: int, on_token):
+        """The decode loop both entry points share."""
+        out, md5s = [], []
+        load = getattr(self.model, "expert_load", None)
+        for i in range(n):
             fp, sampler, md5 = self._resolve_sampler()   # swap boundary
             tok, cache, pos, key = self._step(fp, sampler, md5, params, tok,
                                               cache, pos, key)
             out.append(tok)
             md5s.append(md5)
+            if self.expert_load.on and load is not None:
+                self.expert_load.record(load(cache))
             if on_token is not None:
                 on_token(i, tok)
-        return jnp.stack(out, axis=1), {"sampler_md5s": md5s,
-                                        "rebuilds": self.rebuilds}
+        return out, md5s, cache, pos

@@ -75,7 +75,7 @@ def build_ctx(cfg: RunConfig, mesh=None, rules=None,
                                         and cfg.shape.kind == "decode")
         else "dense",
         moe_impl=cfg.sharding.moe_impl if cfg.sharding.moe_impl != "gshard"
-        else ("ep" if mesh is not None else "dense"),
+        else ("ep" if mesh is not None else "dropless"),
         # ssd_scan_pallas and moe_gmm_pallas have no VJP (kernels/ops.py):
         # the training step takes their XLA paths
         ssd_impl="auto" if decode else "xla",
