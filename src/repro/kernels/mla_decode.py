@@ -16,9 +16,14 @@ and 576 sublanes tile exactly.
 Grid (B / bb, T / bt): ``bb`` batch rows per step, the T axis innermost
 and sequential, carrying the running max, denominator and the [H, r]
 accumulator of each row in VMEM scratch (flash decoding without the
-split over T). The position ``pos`` (one for the batch) is a prefetched
-scalar: tiles past it do no work, and their index map clamps to the last
-needed tile, so they are not fetched either.
+split over T). The cache is every layer's, [L, B, C, T], read in place:
+the layer and the position ``pos`` (one for the batch) are prefetched
+scalars, and the layer axis of the cache's block is squeezed. Tiles
+that hold no position before ``pos`` do no work, and their index map
+clamps to the last needed tile, so they are not fetched either. The new
+latent of position ``pos`` is not in the cache yet (the decode step
+writes it after its layer scan) but comes as a row of its own; it joins
+the running softmax as one more column after the last tile.
 """
 from __future__ import annotations
 
@@ -41,10 +46,10 @@ def _block(n: int, choices) -> int:
     return n
 
 
-def _kernel(pos_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, r, bt, bb, nt):
+def _kernel(sc_ref, q_ref, c_ref, row_ref, o_ref, m_scr, l_scr, acc_scr,
+            *, scale, r, bt, bb, nt):
     j = pl.program_id(1)
-    pos = pos_ref[0]
+    pos = sc_ref[0]
 
     @pl.when(j == 0)
     def _init():
@@ -52,7 +57,17 @@ def _kernel(pos_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j * bt <= pos)
+    def update(b, s, pv):
+        """Fold scores s [H, n] and their values' weighted sum pv(p) in."""
+        m_prev = m_scr[b]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[b] = alpha * l_scr[b] + p.sum(axis=-1, keepdims=True)
+        acc_scr[b] = alpha * acc_scr[b] + pv(p)
+        m_scr[b] = m_new
+
+    @pl.when(j * bt < pos)
     def _tile():
         t = j * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
         for b in range(bb):
@@ -62,47 +77,51 @@ def _kernel(pos_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                      preferred_element_type=jnp.float32)
                  + jax.lax.dot_general(q[:, r:], c[r:], _NN,
                                        preferred_element_type=jnp.float32))
-            s = jnp.where(t <= pos, s * scale, NEG_INF)  # [H, bt]
-            m_prev = m_scr[b]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[b] = alpha * l_scr[b] + p.sum(axis=-1, keepdims=True)
-            acc_scr[b] = alpha * acc_scr[b] + jax.lax.dot_general(
+            s = jnp.where(t < pos, s * scale, NEG_INF)  # [H, bt]
+            update(b, s, lambda p: jax.lax.dot_general(
                 p.astype(c.dtype), cv, _NT,
-                preferred_element_type=jnp.float32)
-            m_scr[b] = m_new
+                preferred_element_type=jnp.float32))
 
     @pl.when(j == nt - 1)
     def _finish():
+        for b in range(bb):
+            q = q_ref[b].astype(jnp.float32)            # [H, C]
+            row = row_ref[b].astype(jnp.float32)        # [1, C]
+            s = (q[:, :r] * row[:, :r]).sum(axis=-1, keepdims=True) \
+                + (q[:, r:] * row[:, r:]).sum(axis=-1, keepdims=True)
+            update(b, s * scale, lambda p: p * row[:, :r])
         o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("r", "scale", "interpret"))
-def mla_decode_pallas(q: jax.Array, cache: jax.Array, pos: jax.Array, *,
-                      r: int, scale: float,
+def mla_decode_pallas(q: jax.Array, cache: jax.Array, layer, row: jax.Array,
+                      pos: jax.Array, *, r: int, scale: float,
                       interpret: bool = False) -> jax.Array:
     """q [B, H, C] (absorbed query: latent part then rope part), cache
-    [B, C, T] latents, pos scalar: the last position attended ->
+    [L, B, C, T] every layer's latents, ``layer`` the one attended, row
+    [B, C, 1] the latent of position ``pos``, the last one attended ->
     [B, H, r], the attention-weighted latent."""
     B, H, C = q.shape
-    T = cache.shape[2]
+    T = cache.shape[3]
     bt = _block(T, (512, 256, 128))
     bb = _block(B, (8, 4, 2, 1))
     nt = T // bt
 
-    def c_map(i, j, pos_ref):
-        return i, 0, jnp.minimum(j, pos_ref[0] // bt)
+    def c_map(i, j, sc):
+        return sc[1], i, 0, jnp.minimum(j, sc[0] // bt)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B // bb, nt),
-        in_specs=[pl.BlockSpec((bb, H, C), lambda i, j, p: (i, 0, 0)),
-                  pl.BlockSpec((bb, C, bt), c_map)],
-        out_specs=pl.BlockSpec((bb, H, r), lambda i, j, p: (i, 0, 0)),
+        in_specs=[pl.BlockSpec((bb, H, C), lambda i, j, sc: (i, 0, 0)),
+                  pl.BlockSpec((pl.Squeezed(), bb, C, bt), c_map),
+                  pl.BlockSpec((bb, 1, C), lambda i, j, sc: (i, 0, 0))],
+        out_specs=pl.BlockSpec((bb, H, r), lambda i, j, sc: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((bb, H, 1), jnp.float32),
                         pltpu.VMEM((bb, H, 1), jnp.float32),
                         pltpu.VMEM((bb, H, r), jnp.float32)])
+    scalars = jnp.stack([jnp.asarray(pos, jnp.int32),
+                         jnp.asarray(layer, jnp.int32)])
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, r=r, bt=bt, bb=bb, nt=nt),
         grid_spec=grid_spec,
@@ -111,18 +130,22 @@ def mla_decode_pallas(q: jax.Array, cache: jax.Array, pos: jax.Array, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mla_decode",
-    )(jnp.reshape(pos, (1,)).astype(jnp.int32), q, cache)
+    )(scalars, q, cache, jnp.reshape(row, (B, 1, C)))
 
 
-def mla_decode_xla(q: jax.Array, cache: jax.Array, pos: jax.Array, *,
-                   r: int, scale: float) -> jax.Array:
-    """The same arithmetic as two einsums over the whole cache (bf16
-    operands, f32 accumulation): scores, then the weighted latent."""
-    s = jnp.einsum("bhc,bct->bht", q, cache,
+def mla_decode_xla(q: jax.Array, cache: jax.Array, layer, row: jax.Array,
+                   pos: jax.Array, *, r: int, scale: float) -> jax.Array:
+    """The same arithmetic as two einsums over the layer's whole cache
+    (bf16 operands, f32 accumulation): scores, then the weighted latent.
+    The layer is read in place, the new row taking position ``pos``
+    through a select that fuses into the einsums."""
+    c = jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+    t = jnp.arange(c.shape[2])
+    c = jnp.where(t == pos, row, c)
+    s = jnp.einsum("bhc,bct->bht", q, c,
                    preferred_element_type=jnp.float32) * scale
-    t = jnp.arange(cache.shape[2])
     s = jnp.where(t[None, None, :] <= pos, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bht,bct->bhc", p.astype(cache.dtype), cache,
+    o = jnp.einsum("bht,bct->bhc", p.astype(c.dtype), c,
                    preferred_element_type=jnp.float32)
     return o[..., :r].astype(q.dtype)

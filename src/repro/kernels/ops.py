@@ -181,14 +181,15 @@ def ragged_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
                               ).astype(lhs.dtype)
 
 
-def latent_decode_attention(q: jax.Array, cache: jax.Array, pos: jax.Array,
-                            *, r: int, scale: float,
-                            impl: str = "auto") -> jax.Array:
-    """Absorbed latent-attention decode: q [B, H, C], cache [B, C, T],
-    positions <= ``pos`` attended -> [B, H, r] (see
+def latent_decode_attention(q: jax.Array, cache: jax.Array, layer,
+                            row: jax.Array, pos: jax.Array, *, r: int,
+                            scale: float, impl: str = "auto") -> jax.Array:
+    """Absorbed latent-attention decode: q [B, H, C] over layer ``layer``
+    of the stacked cache [L, B, C, T], read in place, with the new row
+    [B, C, 1] at ``pos``; positions <= ``pos`` attended -> [B, H, r] (see
     ``kernels/mla_decode.py``)."""
     impl = _auto(impl)
     if impl == "pallas":
         _require_tpu()
-        return mla_decode_pallas(q, cache, pos, r=r, scale=scale)
-    return mla_decode_xla(q, cache, pos, r=r, scale=scale)
+        return mla_decode_pallas(q, cache, layer, row, pos, r=r, scale=scale)
+    return mla_decode_xla(q, cache, layer, row, pos, r=r, scale=scale)

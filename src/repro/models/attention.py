@@ -173,20 +173,43 @@ def kv_cache_axes() -> KVLayerCache:
                         ("batch", "kv_heads", "kv_seq", "head_dim"))
 
 
+def with_row(stack: jax.Array, layer, row: jax.Array, pos, axis: int
+             ) -> jax.Array:
+    """Layer ``layer`` of a stacked cache as a decode step reads it: the
+    stack read in place, with ``row`` in place of position ``pos`` on
+    ``axis`` (of the layer's array). The select is elementwise, so it
+    fuses into its reader; the stack itself is written after the layer
+    scan (``blocks.write_rows``), and rows at positions past ``pos`` may
+    be stale from an earlier turn."""
+    cur = jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+    t = jax.lax.broadcasted_iota(jnp.int32, cur.shape, axis)
+    return jnp.where(t == pos, row, cur)
+
+
 @jax.named_scope("attention")
 def attn_decode(
     p: Dict[str, jax.Array], x: jax.Array, cache: KVLayerCache,
-    pos: jax.Array, cfg: ModelConfig, *,
+    pos: jax.Array, cfg: ModelConfig, *, layer=None,
     window: int = 0, impl: str = "dense", rope: bool = True, prefix: int = 0,
 ) -> Tuple[jax.Array, KVLayerCache]:
-    """x [B,1,d]; pos [] scalar current position. Returns (out, cache)."""
+    """x [B,1,d]; pos [] scalar current position. Without ``layer``,
+    ``cache`` is one layer's, and (out, cache with the new K and V
+    written) is returned. With it, ``cache`` is the stack of every
+    layer's [L, B, Hkv, T, D], read in place and not written, and (out,
+    the new K and V rows [B, Hkv, 1, D]) is returned."""
     B = x.shape[0]
     positions = jnp.full((1,), pos, jnp.int32)
     q, k_new, v_new = _project_qkv(p, x, cfg, positions, rope=rope)
-    k = jax.lax.dynamic_update_slice_in_dim(
-        cache.k, k_new.astype(cache.k.dtype), pos, axis=2)
-    v = jax.lax.dynamic_update_slice_in_dim(
-        cache.v, v_new.astype(cache.v.dtype), pos, axis=2)
+    k_new = k_new.astype(cache.k.dtype)
+    v_new = v_new.astype(cache.v.dtype)
+    if layer is None:
+        k = jax.lax.dynamic_update_slice_in_dim(cache.k, k_new, pos, axis=2)
+        v = jax.lax.dynamic_update_slice_in_dim(cache.v, v_new, pos, axis=2)
+        new = KVLayerCache(k, v)
+    else:
+        k = with_row(cache.k, layer, k_new, pos, axis=2)
+        v = with_row(cache.v, layer, v_new, pos, axis=2)
+        new = KVLayerCache(k_new, v_new)
     if not (isinstance(window, int) and window == 0):
         # sliding-window decode: band mask pos-window < j <= pos
         w = jnp.asarray(window)
@@ -201,7 +224,7 @@ def attn_decode(
         out = ops.attention(q, k.astype(q.dtype), v.astype(q.dtype),
                             causal=False, window=0, impl=impl, kv_len=kv_len)
     y = jnp.einsum("bhsk,hkd->bsd", out, p["wo"])
-    return y, KVLayerCache(k, v)
+    return y, new
 
 
 @jax.named_scope("attention")
@@ -418,26 +441,27 @@ def mla_prefill(p, h, cfg: ModelConfig, *, impl: str = "auto", mesh=None
 
 
 @jax.named_scope("attention")
-def mla_decode(p, h, cache: jax.Array, pos, cfg: ModelConfig, *,
+def mla_decode(p, h, cache: jax.Array, layer, pos, cfg: ModelConfig, *,
                impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
-    """One absorbed decode step. h [B,1,d]; cache [B,r + rope,T]
-    latents; pos [] the new token's position. The new latent is written
-    first; the query folds in W_UK, the output W_UV, and the attention
-    reads the latents only, never per-head keys or values."""
+    """One absorbed decode step of layer ``layer``. h [B,1,d]; cache
+    [L,B,r + rope,T] every layer's latents, read in place and not
+    written; pos [] the new token's position. The query folds in W_UK,
+    the output W_UV, and the attention reads the latents only (the new
+    one at ``pos``), never per-head keys or values. Returns (out
+    [B,1,d], the new latent row [B,r + rope,1])."""
     with jax.named_scope("mla_decode"):
         positions = jnp.full((1,), pos, jnp.int32)
         q = _mla_query(p, h, cfg, positions)[:, :, 0]          # [B,H,qk]
-        lat = _mla_latent(p, h, cfg, positions)               # [B,1,C]
-        cache = jax.lax.dynamic_update_slice_in_dim(
-            cache, jnp.swapaxes(lat, 1, 2).astype(cache.dtype), pos, axis=2)
+        row = jnp.swapaxes(_mla_latent(p, h, cfg, positions),
+                           1, 2).astype(cache.dtype)          # [B,C,1]
         n, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
         q_lat = jnp.einsum("bhk,rhk->bhr", q[..., :n], p["wk_b"],
                            preferred_element_type=jnp.float32)
         q_abs = jnp.concatenate([q_lat.astype(cache.dtype),
                                  q[..., n:].astype(cache.dtype)], axis=-1)
         o_lat = ops.latent_decode_attention(
-            q_abs, cache, pos, r=r, scale=cfg.qk_head_dim() ** -0.5,
-            impl=impl)
+            q_abs, cache, layer, row, pos, r=r,
+            scale=cfg.qk_head_dim() ** -0.5, impl=impl)
         o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(h.dtype), p["wv_b"])
         y = jnp.einsum("bhv,hvd->bd", o, p["wo"])
-        return y[:, None], cache
+        return y[:, None], row

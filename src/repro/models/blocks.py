@@ -272,30 +272,72 @@ def _finish_block(p, x, a_out, s_out, cfg: ModelConfig, ctx: ModelCtx,
 
 
 def block_decode(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
-                 cache: LayerCache, pos, dense_mlp: bool = False
+                 cache: LayerCache, layer, pos, dense_mlp: bool = False
                  ) -> Tuple[jax.Array, LayerCache]:
-    """One-token step. x [B,1,d]."""
+    """One-token step of layer ``layer``. x [B,1,d]; ``cache`` is the
+    stack of every layer's (leading axis the layer), read in place and
+    never written. Returns x and the layer's new entries, for
+    ``write_rows``: the K and V rows [B, Hkv, 1, D] or the latent row
+    [B, C, 1] at ``pos``, and the whole SSM state and expert load; a
+    field the model does not use is size 0."""
     fam = cfg.family
     h = layers.rmsnorm(x, p["norm1"], cfg.norm_eps, ctx.norm_impl)
-    new_kv, new_ssm, new_lat = cache.kv, cache.ssm, cache.latent
+    rows = LayerCache(_empty_kv(), _empty_ssm(), _empty(),
+                      jnp.zeros((0,), cache.expert_load.dtype))
+    new_kv, new_ssm, new_lat = rows.kv, rows.ssm, rows.latent
 
     if fam in ("ssm", "hybrid"):
-        s_out, new_ssm = ssm.ssm_decode(p["ssm"], h, cache.ssm, cfg)
+        s_out, new_ssm = ssm.ssm_decode(
+            p["ssm"], h, jax.tree.map(lambda a: _at(a, layer), cache.ssm),
+            cfg)
     if cfg.is_mla:
-        a_out, new_lat = attn.mla_decode(p["attn"], h, cache.latent, pos, cfg)
+        a_out, new_lat = attn.mla_decode(p["attn"], h, cache.latent, layer,
+                                         pos, cfg)
     elif fam != "ssm":
         if ctx.decode_attn_impl == "seqshard":
-            a_out, new_kv = attn.attn_decode_seqshard(
-                p["attn"], h, cache.kv, pos, cfg, ctx.mesh,
-                axis=ctx.tp_axis, window=window, prefix=cfg.n_meta_tokens)
+            # writes the row inside its shard_map, on the owning rank; the
+            # row is read back from that copy of the layer
+            a_out, kv_l = attn.attn_decode_seqshard(
+                p["attn"], h, jax.tree.map(lambda a: _at(a, layer), cache.kv),
+                pos, cfg, ctx.mesh, axis=ctx.tp_axis, window=window,
+                prefix=cfg.n_meta_tokens)
+            new_kv = jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, pos, 1, axis=2),
+                kv_l)
         else:
             a_out, new_kv = attn.attn_decode(
-                p["attn"], h, cache.kv, pos, cfg, window=window,
+                p["attn"], h, cache.kv, pos, cfg, layer=layer, window=window,
                 impl=ctx.decode_attn_impl, prefix=cfg.n_meta_tokens)
 
     return _finish_block(p, x, a_out if fam != "ssm" else None,
                          s_out if fam in ("ssm", "hybrid") else None, cfg,
-                         ctx, cache, new_kv, new_ssm, new_lat, dense_mlp)
+                         ctx, rows, new_kv, new_ssm, new_lat, dense_mlp)
+
+
+def _at(stack: jax.Array, layer) -> jax.Array:
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def write_rows(cache: LayerCache, rows: LayerCache, pos) -> LayerCache:
+    """The stacked cache after a decode step's layer scan gave back
+    ``rows`` (``block_decode``'s new entries, stacked over the layers):
+    the K, V and latent rows written at ``pos`` on their position axis
+    (3 of [L, B, Hkv, T, D] and of [L, B, C, T]), in place where the
+    cache is donated; the SSM state and expert load whole."""
+    def at_pos(stack, row):
+        if not stack.size:
+            return stack
+        return jax.lax.dynamic_update_slice_in_dim(stack, row, pos, axis=3)
+
+    def whole(stack, new):
+        return new if stack.size else stack
+
+    with jax.named_scope("cache_write"):
+        return LayerCache(
+            kv=jax.tree.map(at_pos, cache.kv, rows.kv),
+            ssm=jax.tree.map(whole, cache.ssm, rows.ssm),
+            latent=at_pos(cache.latent, rows.latent),
+            expert_load=whole(cache.expert_load, rows.expert_load))
 
 
 def layer_windows(cfg: ModelConfig) -> jax.Array:

@@ -217,7 +217,12 @@ class LM:
                     pos: jax.Array, ctx: ModelCtx
                     ) -> Tuple[jax.Array, LayerCache]:
         """token [B] ids; pos scalar absolute position (incl. meta offset).
-        Returns (logits [B,V], new cache)."""
+        Returns (logits [B,V], new cache).
+
+        The layer scan reads each stack's cache in place and never writes
+        it: a layer gives back only its new entries, and one write after
+        the scan puts them into the stack (in place where the caller
+        donates it), so no layer's or stack's cache is ever copied."""
         cfg = self.cfg
         x = layers.embed_lookup(p["embed"], token[:, None], cfg.d_model)
         x = x.astype(cfg.dtype)
@@ -226,18 +231,17 @@ class LM:
         new = []
         for (key, lo, n, dense), cache_s in zip(self._segments(),
                                                 self._split_cache(cache)):
-            def layer_fn(carry, xs, dense=dense):
-                x, pos = carry
-                p_l, w, cache_l = xs
+            def layer_fn(x, xs, dense=dense, cache_s=cache_s):
+                p_l, w, layer = xs
                 p_l = _cast(p_l, cfg.dtype)
-                x, new_cache = blocks.block_decode(
-                    p_l, x, cfg, ctx, uw if uw is not None else w, cache_l,
-                    pos, dense)
-                return (x, pos), new_cache
+                return blocks.block_decode(
+                    p_l, x, cfg, ctx, uw if uw is not None else w, cache_s,
+                    layer, pos, dense)
 
-            (x, _), cache_s = jax.lax.scan(
-                layer_fn, (x, pos), (p[key], windows[lo:lo + n], cache_s))
-            new.append(cache_s)
+            x, rows = jax.lax.scan(
+                layer_fn, x,
+                (p[key], windows[lo:lo + n], jnp.arange(n, dtype=jnp.int32)))
+            new.append(blocks.write_rows(cache_s, rows, pos))
         x = layers.rmsnorm(x, _cast(p["final_norm"], cfg.dtype), cfg.norm_eps,
                            ctx.norm_impl)
         logits = self._unembed(p, x[:, 0])

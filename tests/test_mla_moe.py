@@ -28,23 +28,36 @@ def _moonlight(**kw):
     return get_config("moonlight-16b-a3b").reduced(**kw)
 
 
+@pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("B,T,pos", [(4, 1024, 0), (4, 1024, 511),
+                                     (4, 1024, 512), (4, 1024, 1023),
                                      (8, 768, 700), (2, 256, 255)])
-def test_latent_decode_kernel_matches_xla(B, T, pos):
-    """The Pallas decode kernel (interpreted) and the two-einsum form
-    agree on the weighted latent; tiles past ``pos`` change nothing."""
-    r, rope, H = 64, 16, 16
-    ks = jax.random.split(jax.random.PRNGKey(pos), 2)
+def test_latent_decode_kernel_matches_xla(B, T, pos, layer):
+    """The Pallas decode kernel (interpreted) reads one layer of a stack
+    of three in place, with the new row at ``pos`` (0, the last of a tile,
+    the first of the next, the last of the cache), and agrees with the
+    two-einsum form on that layer with the row written; the cache at
+    ``pos`` and past it, and the other layers, change nothing."""
+    r, rope, H, L = 64, 16, 16, 3
+    ks = jax.random.split(jax.random.PRNGKey(pos), 3)
     q = jax.random.normal(ks[0], (B, H, r + rope), jnp.float32)
-    c = jax.random.normal(ks[1], (B, r + rope, T), jnp.float32)
-    got = mla_decode_pallas(q, c, jnp.int32(pos), r=r, scale=0.1,
-                            interpret=True)
-    want = mla_decode_xla(q, c, jnp.int32(pos), r=r, scale=0.1)
+    c = jax.random.normal(ks[1], (L, B, r + rope, T), jnp.float32)
+    row = jax.random.normal(ks[2], (B, r + rope, 1), jnp.float32)
+    got = mla_decode_pallas(q, c, jnp.int32(layer), row, jnp.int32(pos),
+                            r=r, scale=0.1, interpret=True)
+    written = c[layer].at[:, :, pos].set(row[..., 0])
+    want = mla_decode_xla(q, written[None], jnp.int32(0), row,
+                          jnp.int32(pos), r=r, scale=0.1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
-    garbage = c.at[:, :, pos + 1:].set(1e4)
-    again = mla_decode_pallas(q, garbage, jnp.int32(pos), r=r, scale=0.1,
-                              interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(mla_decode_xla(q, c, jnp.int32(layer), row,
+                                  jnp.int32(pos), r=r, scale=0.1)),
+        np.asarray(want), atol=2e-5, rtol=2e-5)
+    others = jnp.arange(L)[:, None, None, None] != layer
+    garbage = jnp.where(others | (jnp.arange(T) >= pos), 1e4, c)
+    again = mla_decode_pallas(q, garbage, jnp.int32(layer), row,
+                              jnp.int32(pos), r=r, scale=0.1, interpret=True)
     np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
 
 
@@ -58,10 +71,11 @@ def test_absorbed_decode_equals_expanded_attention():
     full, lat = attn.mla_prefill(p, h, cfg, impl="blockwise")
     cache = attn.init_latent_cache(cfg, 2, 16, jnp.float32)
     cache = cache.at[:, :, :S - 1].set(jnp.swapaxes(lat[:, :S - 1], 1, 2))
-    y, cache = attn.mla_decode(p, h[:, S - 1:], cache, jnp.int32(S - 1), cfg)
+    y, row = attn.mla_decode(p, h[:, S - 1:], cache[None], jnp.int32(0),
+                             jnp.int32(S - 1), cfg)
     np.testing.assert_allclose(np.asarray(y[:, 0]), np.asarray(full[:, -1]),
                                atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(cache[:, :, S - 1]),
+    np.testing.assert_allclose(np.asarray(row[..., 0]),
                                np.asarray(lat[:, -1]), atol=1e-6)
 
 

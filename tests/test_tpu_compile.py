@@ -15,6 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.mla_decode import mla_decode_pallas
 from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
@@ -115,3 +116,21 @@ def test_moe_gmm_compiles(one_chip):
     rhs = jax.ShapeDtypeStruct((8, 1024, 512), jnp.bfloat16,
                                sharding=one_chip)
     assert _kernel_named(_compiled_text(moe_gmm_pallas, lhs, rhs), "moe_gmm")
+
+
+def test_mla_decode_reads_a_stacked_cache_in_place(one_chip):
+    """moonlight-serve-8k's latent decode: the kernel takes every layer's
+    [4, 64, 576, 7936] cache whole, with the layer as a prefetched
+    scalar, and the program slices and copies no layer out of it."""
+    L, B, H, C, T = 4, 64, 16, 576, 7936
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compiled_text(
+        lambda q, c, l, row, pos: mla_decode_pallas(q, c, l, row, pos,
+                                                    r=512, scale=0.07),
+        sds((B, H, C)), sds((L, B, C, T)), sds((), jnp.int32),
+        sds((B, C, 1)), sds((), jnp.int32))
+    assert _kernel_named(text, "mla_decode")
+    assert f"[{B},{C},{T}]" not in text
