@@ -1,5 +1,5 @@
-"""Kernel micro-benchmarks (XLA paths, CPU wall-time): the blockwise
-triangular schedule vs full-rectangle, SSD chunked vs naive scan."""
+"""Kernel micro-benchmarks (XLA paths, CPU wall-time): blockwise
+attention vs dense, SSD chunked vs naive scan."""
 from __future__ import annotations
 
 import time
@@ -31,16 +31,11 @@ def main(report) -> None:
 
     rect = jax.jit(lambda q, k, v: xla.attention_blockwise(
         q, k, v, causal=True, block_kv=256))
-    tri = jax.jit(lambda q, k, v: xla.attention_blockwise(
-        q, k, v, causal=True, block_kv=256, triangular=True))
     dense = jax.jit(lambda q, k, v: ref.attention_ref(q, k, v, causal=True))
     t_r = timeit(rect, q, k, v)
-    t_t = timeit(tri, q, k, v)
     t_d = timeit(dense, q, k, v)
     report("attn_dense_1k", t_d * 1e6, f"{t_d*1e3:.1f} ms")
     report("attn_blockwise_1k", t_r * 1e6, f"{t_r*1e3:.1f} ms")
-    report("attn_triangular_1k", t_t * 1e6,
-           f"{t_t*1e3:.1f} ms (x{t_r/t_t:.2f} vs rect)")
 
     Bs, Ss, Hh, P, N = 2, 2048, 8, 64, 64
     x = jax.random.normal(ks[0], (Bs, Ss, Hh, P))

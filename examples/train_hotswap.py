@@ -6,7 +6,7 @@ swap and a checkpoint/restore cycle mid-run.
 
 (The full-width smollm-135m runs the same code path on one TPU via
 ``python -m repro.launch.train --arch smollm-135m --seq 1024 --batch 8``
-and ``chip_smoke.py``; the dry-run proves the sharded lowering.)
+and ``chip_smoke.py``.)
 """
 import argparse
 import dataclasses

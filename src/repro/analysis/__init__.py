@@ -1,6 +1,5 @@
-"""Roofline analysis from compiled dry-run artifacts."""
+"""Loop-aware HLO cost analysis and the three-term roofline."""
 from repro.analysis.hlo import collective_bytes, parse_collectives
-from repro.analysis.roofline import RooflineTerms, roofline_from_artifacts
+from repro.analysis.roofline import RooflineTerms
 
-__all__ = ["RooflineTerms", "collective_bytes", "parse_collectives",
-           "roofline_from_artifacts"]
+__all__ = ["RooflineTerms", "collective_bytes", "parse_collectives"]

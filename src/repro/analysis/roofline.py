@@ -1,4 +1,4 @@
-"""Three-term roofline from the compiled dry-run artifact.
+"""Three-term roofline of a compiled program.
 
 TPU v5e per chip: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
 The compiled module is the per-device SPMD program, so cost_analysis
@@ -15,7 +15,7 @@ much of the compiled compute is "useful" (remat/dispatch/padding waste).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 PEAK_FLOPS = 197e12       # bf16 / chip
 HBM_BW = 819e9            # B/s / chip
@@ -102,45 +102,3 @@ class RooflineTerms:
             "roofline_fraction": self.roofline_fraction,
             "collectives": self.collective_detail,
         }
-
-
-def model_flops_estimate(model_cfg, shape_cfg, kind: str) -> float:
-    """6*N_active*D for train, 2*N_active*D for inference forward."""
-    n = model_cfg.active_param_count()
-    if kind == "train":
-        tokens = shape_cfg.global_batch * shape_cfg.seq_len
-        return 6.0 * n * tokens
-    if kind == "prefill":
-        tokens = shape_cfg.global_batch * shape_cfg.seq_len
-        return 2.0 * n * tokens
-    # decode: one token per sequence in the batch
-    return 2.0 * n * shape_cfg.global_batch
-
-
-def roofline_from_artifacts(
-    *, arch: str, shape: str, mesh_name: str, chips: int,
-    cost: Dict[str, Any], hlo_text: str, memory: Any,
-    model_cfg=None, shape_cfg=None, kind: str = "train",
-) -> RooflineTerms:
-    """flops/bytes come from our loop-aware HLO analyzer (XLA's
-    cost_analysis counts while bodies once — see analysis/hlo.py);
-    ``cost`` is kept in the artifact JSON as a cross-check only."""
-    from repro.analysis.hlo import analyze
-
-    stats = analyze(hlo_text)
-    mf = (model_flops_estimate(model_cfg, shape_cfg, kind)
-          if model_cfg is not None else 0.0)
-    peak_mem = 0.0
-    if memory is not None:
-        peak_mem = (getattr(memory, "temp_size_in_bytes", 0)
-                    + getattr(memory, "argument_size_in_bytes", 0)
-                    + getattr(memory, "output_size_in_bytes", 0)
-                    - getattr(memory, "alias_size_in_bytes", 0))
-    return RooflineTerms(
-        arch=arch, shape=shape, mesh=mesh_name, chips=chips,
-        flops_per_chip=stats.flops, bytes_per_chip=stats.hbm_bytes,
-        fused_bytes_per_chip=stats.fused_bytes,
-        wire_bytes_per_chip=stats.total_wire,
-        model_flops=mf, peak_memory_bytes=peak_mem,
-        collective_detail=stats.as_dict(),
-    )

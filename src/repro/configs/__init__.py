@@ -6,11 +6,9 @@ overrides).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict
 
 from repro.configs.base import (
-    LONG_CONTEXT_OK,
-    MULTI_POD,
     SHAPES,
     SINGLE_POD,
     MeshConfig,
@@ -20,7 +18,6 @@ from repro.configs.base import (
     ShardingConfig,
     ShapeConfig,
     TrainConfig,
-    shape_supported,
 )
 
 from repro.configs.kimi_k2_1t_a32b import CONFIG as _kimi, TRAIN as _kimi_train
@@ -92,44 +89,13 @@ def get_train_config(name: str) -> TrainConfig:
     return _TRAIN_OVERRIDES.get(name, TrainConfig())
 
 
-# §Perf winners (EXPERIMENTS.md): per-arch optimized knobs. Baselines
-# stay the default so reproduction and beyond-paper gains are separate.
-_OPTIMIZED: Dict[str, Dict] = {
-    "smollm-135m": dict(
-        sharding=ShardingConfig(attn_impl="ctxpar",
-                                seq_shard_activations=True)),
-    "kimi-k2-1t-a32b": dict(
-        train=TrainConfig(optimizer="adafactor", num_microbatches=1,
-                          grad_accum_dtype="bfloat16",
-                          remat_policy="dots"),
-        sharding=ShardingConfig(seq_shard_activations=True)),
-    "yi-34b": dict(
-        train=TrainConfig(num_microbatches=1, remat_policy="dots",
-                          zero1=True),
-        sharding=ShardingConfig(attn_impl="ctxpar",
-                                seq_shard_activations=True,
-                                fsdp_params=False)),
-}
-
-
-def make_run_config(
-    arch: str,
-    shape: str,
-    *,
-    multi_pod: bool = False,
-    preset: str = "baseline",      # baseline | optimized
-    **overrides,
-) -> RunConfig:
-    model = get_config(arch)
+def make_run_config(arch: str, shape: str, **overrides) -> RunConfig:
     cfg = RunConfig(
-        model=model,
+        model=get_config(arch),
         shape=SHAPES[shape],
-        mesh=MULTI_POD if multi_pod else SINGLE_POD,
         train=get_train_config(arch),
         sharding=_SHARDING_OVERRIDES.get(arch, ShardingConfig()),
     )
-    if preset == "optimized":
-        cfg = cfg.replace(**_OPTIMIZED.get(arch, {}))
     if overrides:
         cfg = cfg.replace(**overrides)
     return cfg
@@ -139,9 +105,7 @@ __all__ = [
     "ARCH_REGISTRY",
     "ARCH_NAMES",
     "SHAPES",
-    "LONG_CONTEXT_OK",
     "SINGLE_POD",
-    "MULTI_POD",
     "ModelConfig",
     "ShapeConfig",
     "MeshConfig",
@@ -152,5 +116,4 @@ __all__ = [
     "get_config",
     "get_train_config",
     "make_run_config",
-    "shape_supported",
 ]

@@ -3,13 +3,13 @@
 Every assigned architecture is expressed as a frozen ``ModelConfig``;
 input shapes are ``ShapeConfig`` entries from the public shape table;
 ``RunConfig`` binds (model, shape, mesh, train/serve knobs) for the
-launchers and the dry-run.
+launchers.
 """
 from __future__ import annotations
 
 import dataclasses
-from dataclasses import dataclass, field
-from typing import Any, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Tuple
 
 # ---------------------------------------------------------------------------
 # Model configuration
@@ -267,16 +267,6 @@ SHAPES = {
     "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
 }
 
-# Archs allowed to run the sub-quadratic long-context decode shape.
-LONG_CONTEXT_OK = ("mamba2-370m", "hymba-1.5b")
-
-
-def shape_supported(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Whether an (arch, shape) cell is runnable; reason if not."""
-    if shape.name == "long_500k" and model.name not in LONG_CONTEXT_OK:
-        return False, "pure full-attention arch: long_500k needs sub-quadratic attention (skip per assignment)"
-    return True, ""
-
 
 # ---------------------------------------------------------------------------
 # Mesh / run configuration
@@ -287,20 +277,8 @@ class MeshConfig:
     shape: Tuple[int, ...] = (16, 16)
     axes: Tuple[str, ...] = ("data", "model")
 
-    @property
-    def num_devices(self) -> int:
-        n = 1
-        for s in self.shape:
-            n *= s
-        return n
-
-    @property
-    def multi_pod(self) -> bool:
-        return "pod" in self.axes
-
 
 SINGLE_POD = MeshConfig((16, 16), ("data", "model"))
-MULTI_POD = MeshConfig((2, 16, 16), ("pod", "data", "model"))
 
 
 @dataclass(frozen=True)
@@ -336,8 +314,6 @@ class ShardingConfig:
     tp_axis: str = "model"
     batch_axes: Tuple[str, ...] = ("pod", "data")
     seq_shard_activations: bool = False  # SP: shard saved residuals' seq over model
-    moe_impl: str = "gshard"           # gshard | ep_shardmap
-    attn_impl: str = "auto"            # auto | blockwise | dense | pallas | ctxpar
     fsdp_params: bool = True           # FSDP-shard params over data axis
 
 

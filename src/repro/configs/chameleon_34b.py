@@ -4,7 +4,7 @@ Assignment table: 48L d_model=8192 64H (GQA kv=8) d_ff=22016 vocab=65536.
 
 Early fusion means image patches are VQ-quantized into ordinary token ids
 inside the 65536 vocab; the transformer backbone is a plain decoder-only
-LM. Per the assignment, the VQ frontend is a STUB: ``input_specs()``
+LM. Per the assignment, the VQ frontend is a STUB: the caller
 provides precomputed token ids (text + image-token spans interleaved).
 Chameleon uses qk-norm for training stability.
 """

@@ -2,7 +2,7 @@
 
 Assignment table: 32L d_model=1280 20H (kv=20, i.e. MHA) d_ff=5120
 vocab=51866. Encoder and decoder are both 32 layers; the mel->conv
-frontend is a STUB per the assignment — ``input_specs()`` provides
+frontend is a STUB per the assignment — the caller provides
 precomputed frame embeddings [B, 1500, 1280].
 """
 from repro.configs.base import ModelConfig

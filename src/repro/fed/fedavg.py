@@ -32,7 +32,7 @@ session drives in-proc fleets and the sharded multi-process TCP fleet.
 
 The model here is a linear-regression-with-features head (flat
 parameter vector) — deliberately small so a fleet round is
-milliseconds; the pod-scale LM path lives in train/ and launch/.
+milliseconds; the LM path lives in train/ and launch/.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the substrate's compute hot spots.
 
 The paper (OODIDA active-code replacement) has no kernel-level
-contribution; these kernels serve the pod-scale substrate's hot spots
+contribution; these kernels serve the model substrate's hot spots
 (attention, SSD scan, RMSNorm, grouped expert matmul). Layout per the
 deliverable spec: ``<name>.py`` holds the ``pl.pallas_call`` + BlockSpec
 kernel, ``ops.py`` the jit'd dispatch wrappers, ``ref.py`` the pure-jnp

@@ -9,8 +9,8 @@ tile of (bc, bn). Tiles default to 128x128(x512 K-step): MXU-aligned,
 
 (A megablox-style *ragged* layout would avoid padding to capacity; the
 capacity layout was chosen because it keeps all shapes static across
-iterations — required for the fixed-shape pjit dry-run — and matches
-the GShard-family dispatch in models/moe.py.)
+iterations, and matches the expert-parallel dispatch in models/moe.py;
+the dropless path there takes ``ops.ragged_gmm`` instead.)
 """
 from __future__ import annotations
 

@@ -2,24 +2,25 @@
 
 Model code calls these; ``impl`` selects the backend:
 
-* ``"ref"``      — pure-jnp oracle (tests)
-* ``"xla"``      — efficient pure-XLA path (what the CPU dry-run lowers;
-                   the baseline on real hardware too)
+* ``"ref"``      — pure-jnp oracle (tests; decode attention)
 * ``"pallas"``   — Pallas TPU kernel; raises on any other backend (tests
                    call the kernels themselves with ``interpret=True``)
-* ``"auto"``     — xla on CPU, pallas on TPU; for attention, the Pallas
-                   kernel only where ``flash_qualifies`` says it can take
-                   the call, the blockwise XLA path otherwise
+* ``"auto"``     — pallas on a TPU, the XLA path elsewhere; for
+                   attention, the Pallas kernel only where
+                   ``flash_qualifies`` says it can take the call
+* ``"xla"``      — the pure-XLA path of ``kernels/xla.py``; attention
+                   calls it ``"blockwise"`` and refuses any other name
+
+This is where ``"auto"`` is decided, from what the call can observe: the
+backend, and for attention the shapes and the mesh. ``ModelCtx`` holds a
+caller's override (``models/blocks.py``).
 
 ``rmsnorm_pallas`` and ``flash_attention_pallas`` have VJPs.
 ``ssd_scan_pallas``, ``moe_gmm_pallas`` and ``mla_decode_pallas`` are
-forward-only, so the training step (``train.step.build_ctx``) routes
-``ssd`` and ``gmm`` to ``"xla"``; a gradient taken through their
+forward-only, so the training step (``ModelCtx``'s defaults) takes the
+``"xla"`` paths of ``ssd`` and ``gmm``; a gradient taken through their
 ``"pallas"`` paths fails in JAX's linearization. ``ragged_gmm``'s Pallas
 path is megablox's ``gmm``, which has a VJP.
-
-Attention additionally supports the schedule variants of the XLA path
-(``blockwise`` / ``blockwise_tri`` / ``dense``).
 """
 from __future__ import annotations
 
@@ -88,7 +89,7 @@ def attention(
     q_start=None, mesh=None,
 ) -> jax.Array:
     """q [B,Hq,Sq,D]; k,v [B,Hkv,Skv,D]. ``kv_len`` masks a dynamic KV
-    prefix (decode); only dense/blockwise support it. ``window`` may be a
+    prefix (decode); only ref/blockwise support it. ``window`` may be a
     traced scalar for the xla paths (0 => full); ``prefix`` keys are
     always visible (hymba meta tokens); ``q_start`` overrides the queries'
     absolute start (blockwise only). ``mesh`` is the mesh the call runs
@@ -107,16 +108,8 @@ def attention(
     if impl == "ref":
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
                                   scale=scale, kv_len=kv_len, prefix=prefix)
-    if impl == "dense":
-        return _ref.attention_ref(q, k, v, causal=causal, window=window,
-                                  scale=scale, kv_len=kv_len, prefix=prefix)
-    if (impl == "blockwise_tri" and isinstance(window, int)
-            and (prefix == 0 or window == 0)):
-        return _xla.attention_blockwise(q, k, v, causal=causal, window=window,
-                                        scale=scale, block_kv=block_kv,
-                                        triangular=True, prefix=prefix,
-                                        q_start=q_start)
-    # default xla / blockwise (also blockwise_tri fallback for traced window)
+    if impl != "blockwise":
+        raise ValueError(f"unknown attention impl {impl!r}")
     return _xla.attention_blockwise(q, k, v, causal=causal, window=window,
                                     scale=scale, block_kv=block_kv,
                                     kv_len=kv_len, prefix=prefix,

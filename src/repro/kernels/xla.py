@@ -1,22 +1,15 @@
 """Efficient pure-XLA implementations of the kernel ops.
 
-These are what the multi-pod dry-run lowers (the container cannot emit
-Mosaic TPU code); on real v5e the Pallas kernels take over via the
-``impl`` switch in ops.py. Numerics match ref.py (tested).
+The ``impl`` switch in ops.py takes these off a TPU, and on a TPU where
+a Pallas kernel cannot take the call (attention with a traced window, a
+prefix, a dynamic KV length or a sharded mesh). Numerics match ref.py
+(tested).
 
-Two causal-attention schedules are provided:
-
-* ``blockwise``      — lax.scan over KV blocks with masking. Simple,
-                       but computes the full Sq x Skv rectangle
-                       (~2x FLOP waste when causal).
-* ``blockwise_tri``  — statically unrolled triangular schedule: each Q
-                       block attends only to its KV prefix. Halves
-                       attention FLOPs at the cost of a larger HLO.
-                       (A hillclimb lever — see EXPERIMENTS.md §Perf.)
+Causal attention is ``attention_blockwise``: a lax.scan over KV blocks
+with masking, which computes the full Sq x Skv rectangle.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -63,15 +56,13 @@ def _online_update(carry, kblk, vblk, q, q_pos, k_pos, scale, causal, window,
 def attention_blockwise(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True, window: int = 0, scale: Optional[float] = None,
-    block_kv: int = 512, triangular: bool = False,
-    kv_len: Optional[jax.Array] = None, prefix: int = 0,
+    block_kv: int = 512, kv_len: Optional[jax.Array] = None, prefix: int = 0,
     q_start=None,
 ) -> jax.Array:
     """Online-softmax attention. q [B,Hq,Sq,D]; k,v [B,Hkv,Skv,D].
 
     ``q_start`` overrides the queries' absolute start position (default
-    Skv - Sq, the decode-offset convention); the context-parallel path
-    passes the shard offset (may be traced)."""
+    Skv - Sq, the decode-offset convention; may be traced)."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     group = Hq // Hkv
@@ -85,70 +76,22 @@ def attention_blockwise(
         block_kv //= 2
     nkv = Skv // block_kv
     q_off = (Skv - Sq) if q_start is None else q_start
-    if q_start is not None:
-        triangular = False       # triangular schedule needs static offsets
+    ks = kf.reshape(B, Hq, nkv, block_kv, D).transpose(2, 0, 1, 3, 4)
+    vs = vf.reshape(B, Hq, nkv, block_kv, D).transpose(2, 0, 1, 3, 4)
+    q_pos = jnp.arange(Sq) + q_off
 
-    if not triangular:
-        ks = kf.reshape(B, Hq, nkv, block_kv, D).transpose(2, 0, 1, 3, 4)
-        vs = vf.reshape(B, Hq, nkv, block_kv, D).transpose(2, 0, 1, 3, 4)
-        q_pos = jnp.arange(Sq) + q_off
+    def body(carry, blk):
+        kblk, vblk, j = blk
+        k_pos = j * block_kv + jnp.arange(block_kv)
+        return _online_update(carry, kblk, vblk, qf, q_pos, k_pos, scale,
+                              causal, window, kv_len, prefix), None
 
-        def body(carry, blk):
-            kblk, vblk, j = blk
-            k_pos = j * block_kv + jnp.arange(block_kv)
-            return _online_update(carry, kblk, vblk, qf, q_pos, k_pos, scale,
-                                  causal, window, kv_len, prefix), None
-
-        init = (jnp.full((B, Hq, Sq), NEG_INF, jnp.float32),
-                jnp.zeros((B, Hq, Sq), jnp.float32),
-                jnp.zeros((B, Hq, Sq, D), jnp.float32))
-        (m, l, acc), _ = jax.lax.scan(body, init,
-                                      (ks, vs, jnp.arange(nkv)))
-        out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return out.astype(q.dtype)
-
-    # --- triangular static schedule (causal only) ---
-    assert causal and kv_len is None, "triangular schedule is for causal training"
-    assert isinstance(window, int), "triangular schedule needs a static window"
-    block_q = block_kv
-    while Sq % block_q:
-        block_q //= 2
-    nq = Sq // block_q
-    outs = []
-    for qi in range(nq):
-        q_blk = jax.lax.slice_in_dim(qf, qi * block_q, (qi + 1) * block_q, axis=2)
-        q_pos = qi * block_q + jnp.arange(block_q) + q_off
-        # static KV prefix: only blocks that intersect the causal band
-        hi = min(Skv, (qi + 1) * block_q + q_off)
-        lo = 0
-        if window:
-            lo = max(0, (qi * block_q + q_off) - (window - 1))
-            lo = (lo // block_kv) * block_kv
-        hi = ((hi + block_kv - 1) // block_kv) * block_kv
-        kpre = jax.lax.slice_in_dim(kf, lo, hi, axis=2)
-        vpre = jax.lax.slice_in_dim(vf, lo, hi, axis=2)
-        npre = (hi - lo) // block_kv
-        ks = kpre.reshape(B, Hq, npre, block_kv, D).transpose(2, 0, 1, 3, 4)
-        vs = vpre.reshape(B, Hq, npre, block_kv, D).transpose(2, 0, 1, 3, 4)
-
-        def body(carry, blk, q_blk=q_blk, q_pos=q_pos, lo=lo):
-            kblk, vblk, j = blk
-            k_pos = lo + j * block_kv + jnp.arange(block_kv)
-            return _online_update(carry, kblk, vblk, q_blk, q_pos, k_pos,
-                                  scale, True, window), None
-
-        init = (jnp.full((B, Hq, block_q), NEG_INF, jnp.float32),
-                jnp.zeros((B, Hq, block_q), jnp.float32),
-                jnp.zeros((B, Hq, block_q, D), jnp.float32))
-        (m, l, acc), _ = jax.lax.scan(body, init, (ks, vs, jnp.arange(npre)))
-        outs.append(acc / jnp.maximum(l, 1e-30)[..., None])
-    return jnp.concatenate(outs, axis=2).astype(q.dtype)
-
-
-def attention_dense(q, k, v, *, causal=True, window=0, scale=None, kv_len=None):
-    from repro.kernels.ref import attention_ref
-    return attention_ref(q, k, v, causal=causal, window=window, scale=scale,
-                         kv_len=kv_len)
+    init = (jnp.full((B, Hq, Sq), NEG_INF, jnp.float32),
+            jnp.zeros((B, Hq, Sq), jnp.float32),
+            jnp.zeros((B, Hq, Sq, D), jnp.float32))
+    (m, l, acc), _ = jax.lax.scan(body, init, (ks, vs, jnp.arange(nkv)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

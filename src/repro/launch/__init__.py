@@ -1,1 +1,1 @@
-"""Launchers: production mesh, dry-run sweep, train/serve drivers."""
+"""Launchers: mesh construction, train/serve drivers, the TCP fleet."""

@@ -1,26 +1,18 @@
-"""Abstract input/state specs for the dry-run (ShapeDtypeStruct only —
+"""Abstract train state and its shardings (ShapeDtypeStruct only —
 weak-type-correct, shardable, zero device allocation)."""
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig, RunConfig
-from repro.models import build_model
-from repro.optim.api import Optimizer, build_optimizer
-from repro.sharding.auto import run_rules, sanitize_spec, sanitize_tree, shardings_for
-from repro.sharding.specs import AxisRules, logical_to_spec, param_specs_for_tree
+from repro.configs.base import RunConfig
+from repro.optim.api import Optimizer
+from repro.sharding.auto import shardings_for
+from repro.sharding.specs import AxisRules
 from repro.train.state import TrainState
-from repro.train.step import build_ctx
-
-
-def abstract_params(model) -> Any:
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    return jax.eval_shape(model.init, rng)
 
 
 def abstract_state(model, optimizer: Optimizer, run_cfg: RunConfig) -> Any:
@@ -28,70 +20,6 @@ def abstract_state(model, optimizer: Optimizer, run_cfg: RunConfig) -> Any:
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
     return jax.eval_shape(
         lambda r: init_state(model, optimizer, r, run_cfg), rng)
-
-
-def input_specs(run_cfg: RunConfig, mesh: Mesh,
-                rules: Optional[AxisRules] = None) -> Dict[str, Any]:
-    """Host-input ShapeDtypeStructs with shardings for the step kind."""
-    if rules is None:
-        rules = run_rules(run_cfg)
-    cfg = run_cfg.model
-    shp = run_cfg.shape
-    B, S = shp.global_batch, shp.seq_len
-    bspec = sanitize_spec((B, S), logical_to_spec(
-        ("batch", "seq"), rules), mesh)
-    tok = jax.ShapeDtypeStruct((B, S), jnp.int32,
-                               sharding=NamedSharding(mesh, bspec))
-    out: Dict[str, Any] = {}
-    if shp.kind == "train":
-        # microbatched batches arrive pre-reshaped [M, B/M, ...] so the
-        # scan slices an unsharded leading dim (no per-step resharding)
-        M = max(1, run_cfg.train.num_microbatches)
-
-        def shaped(shape, dtype, axes):
-            if M > 1:
-                shape = (M,) + (shape[0] // M,) + shape[1:]
-                axes = (None,) + axes
-            spec = sanitize_spec(shape, logical_to_spec(axes, rules), mesh)
-            return jax.ShapeDtypeStruct(shape, dtype,
-                                        sharding=NamedSharding(mesh, spec))
-
-        out["tokens"] = shaped((B, S), jnp.int32, ("batch", "seq"))
-        out["labels"] = shaped((B, S), jnp.int32, ("batch", "seq"))
-        if cfg.is_encoder_decoder or cfg.frontend == "audio_stub":
-            out["frames"] = shaped(
-                (B, cfg.encoder_seq, cfg.d_model), jnp.dtype(cfg.dtype),
-                ("batch", None, None))
-        return out
-    if shp.kind == "prefill":
-        out["tokens"] = tok
-        if cfg.is_encoder_decoder:
-            fspec = sanitize_spec(
-                (B, cfg.encoder_seq, cfg.d_model),
-                logical_to_spec(("batch", None, None), rules), mesh)
-            out["frames"] = jax.ShapeDtypeStruct(
-                (B, cfg.encoder_seq, cfg.d_model), jnp.dtype(cfg.dtype),
-                sharding=NamedSharding(mesh, fspec))
-        return out
-    # decode: one new token against a seq_len KV cache
-    tspec = sanitize_spec((B,), logical_to_spec(("batch",), rules), mesh)
-    out["token"] = jax.ShapeDtypeStruct((B,), jnp.int32,
-                                        sharding=NamedSharding(mesh, tspec))
-    out["pos"] = jax.ShapeDtypeStruct((), jnp.int32,
-                                      sharding=NamedSharding(mesh, P()))
-    out["key"] = jax.ShapeDtypeStruct((2,), jnp.uint32,
-                                      sharding=NamedSharding(mesh, P()))
-    return out
-
-
-def abstract_cache(model, run_cfg: RunConfig, ctx) -> Any:
-    B, S = run_cfg.shape.global_batch, run_cfg.shape.seq_len
-    return jax.eval_shape(
-        functools.partial(model.init_cache, B, S, ctx))
-
-
-def cache_shardings(model, cache_sds, rules: AxisRules, mesh: Mesh) -> Any:
-    return shardings_for(cache_sds, model.cache_axes(), rules, mesh)
 
 
 def param_shardings(model, params_sds, rules: AxisRules, mesh: Mesh) -> Any:

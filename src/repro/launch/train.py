@@ -5,7 +5,7 @@ bindings and the ``TrainLoop``, with checkpoint/restore and a save on
 preemption. ``--reduced`` shrinks the model to a CPU-sized config;
 ``--seq``/``--batch`` override the shape's sequence length and global
 batch on their own, so a full-width model can run at a size one chip
-holds. (The sharded multi-pod lowering is exercised by ``dryrun.py``.)
+holds.
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
         --steps 200 --reduced --seq 128 --batch 8 --ckpt /tmp/ckpt

@@ -1,13 +1,13 @@
 """Attention block: GQA projections, optional qk-norm, RoPE, KV cache.
 
-Train/prefill call into kernels.ops.attention (blockwise / triangular /
-pallas); decode does a cache update + masked attention over the cache.
+Train/prefill call into kernels.ops.attention (flash or blockwise);
+decode does a cache update + masked attention over the cache.
 Logical axes: heads are tensor-parallel ("heads" -> model axis), the
 embed dim of every projection is the FSDP dim.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,8 +64,7 @@ def attn_apply(
     causal: bool = True, window: int = 0, impl: str = "blockwise",
     rope: bool = True, positions: Optional[jax.Array] = None,
     kv: Optional[Tuple[jax.Array, jax.Array]] = None, prefix: int = 0,
-    mesh=None, tp_axis: str = "model",
-    batch_axes: Tuple[str, ...] = ("pod", "data"),
+    mesh=None,
 ) -> jax.Array:
     """Full-sequence attention (train / prefill / encoder).
 
@@ -84,63 +83,9 @@ def attn_apply(
         if rope:
             q = layers.apply_rope(q, positions, cfg.rope_theta)
         k, v = kv
-    if impl == "ctxpar":
-        out = attn_ctxpar(q, k, v, mesh, axis=tp_axis, causal=causal,
-                          window=window, prefix=prefix,
-                          batch_axes=batch_axes)
-    else:
-        out = ops.attention(q, k, v, causal=causal, window=window,
-                            impl=impl, prefix=prefix, mesh=mesh)
+    out = ops.attention(q, k, v, causal=causal, window=window, impl=impl,
+                        prefix=prefix, mesh=mesh)
     return jnp.einsum("bhsk,hkd->bsd", out, p["wo"])
-
-
-def attn_ctxpar(q, k, v, mesh, *, axis: str = "model", causal: bool = True,
-                window: int = 0, prefix: int = 0,
-                batch_axes: Tuple[str, ...] = ("pod", "data")) -> jax.Array:
-    """Context-parallel attention over the TP axis.
-
-    For archs whose head counts do not divide the TP degree (smollm 9H,
-    yi 56H, whisper 20H, hymba 25H on a 16-way axis) attention would
-    otherwise be *replicated* across all TP ranks — 16x wasted flops and
-    score-matrix traffic. Instead the QUERY sequence is sharded over the
-    TP axis (each rank computes its Sq/n rows against the full K/V) and
-    outputs concatenate for free along the sharded seq dim. K/V are
-    gathered once per layer ([B,Hkv,S,D] — MBs) against an S^2-sized
-    compute saving. Exact: masking uses absolute positions via q_start.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    n = mesh.shape[axis]
-    S = q.shape[2]
-    assert S % n == 0, (S, n)
-    S_l = S // n
-    # fully-manual region: a partial-manual shard_map would force the
-    # batch dim replicated over the (auto) data axis at the boundary —
-    # a 16x gather of every activation (measured; see EXPERIMENTS §Perf)
-    b_axes = tuple(a for a in batch_axes if a in mesh.shape)
-    bspec = b_axes if len(b_axes) > 1 else (b_axes[0] if b_axes else None)
-
-    def body(q_l, k_l, v_l):
-        r = jax.lax.axis_index(axis)
-        # explicit K/V all-gather (one [B_l,Hkv,S,D] gather per layer —
-        # MBs, vs the S^2 compute this shards 16 ways). f32 at the
-        # boundary: the online-softmax computes in f32 anyway, and
-        # XLA:CPU's AllReducePromotion pass crashes on bf16 gathers.
-        k_f = jax.lax.all_gather(k_l.astype(jnp.float32), axis, axis=2,
-                                 tiled=True)
-        v_f = jax.lax.all_gather(v_l.astype(jnp.float32), axis, axis=2,
-                                 tiled=True)
-        return ops.attention(q_l, k_f, v_f, causal=causal, window=window,
-                             impl="blockwise", prefix=prefix,
-                             q_start=r * S_l)
-
-    spec = P(bspec, None, axis, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        axis_names=set(mesh.axis_names), check_vma=False,
-    )(q, k, v)
 
 
 def cross_kv(p: Dict[str, jax.Array], enc: jax.Array, cfg: ModelConfig,
@@ -190,7 +135,7 @@ def with_row(stack: jax.Array, layer, row: jax.Array, pos, axis: int
 def attn_decode(
     p: Dict[str, jax.Array], x: jax.Array, cache: KVLayerCache,
     pos: jax.Array, cfg: ModelConfig, *, layer=None,
-    window: int = 0, impl: str = "dense", rope: bool = True, prefix: int = 0,
+    window: int = 0, impl: str = "ref", rope: bool = True, prefix: int = 0,
 ) -> Tuple[jax.Array, KVLayerCache]:
     """x [B,1,d]; pos [] scalar current position. Without ``layer``,
     ``cache`` is one layer's, and (out, cache with the new K and V

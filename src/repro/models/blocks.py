@@ -22,15 +22,28 @@ from repro.sharding.specs import AxisRules, constrain
 @dataclass(frozen=True)
 class ModelCtx:
     """Everything a model fwd needs besides params: mesh + sharding rules
-    and kernel/impl selection."""
+    and the caller's kernel overrides.
+
+    Each kernel choice has one home. ``"auto"`` is decided in
+    ``kernels/ops.py`` from what the call can observe: the backend, and
+    for attention the shapes and mesh (``flash_qualifies``).
+    ``moe_impl="auto"`` is decided in ``moe.moe_apply``: expert parallel
+    under a mesh, dropless without. The defaults here are the one-device
+    training step's; ``train.step.build_ctx`` adds only what the call
+    site knows: the mesh and rules, and for decode the forward-only
+    Pallas kernels (``ssd``, ``gmm``) and, under a mesh,
+    sequence-sharded decode attention. Tests pass ``"ref"``,
+    ``"blockwise"`` or ``moe_impl="dense"`` to pin a path."""
     mesh: Any = None
     rules: Optional[AxisRules] = None
-    attn_impl: str = "blockwise"
-    decode_attn_impl: str = "dense"
-    moe_impl: str = "ep"            # ep | dense
+    attn_impl: str = "auto"         # auto | blockwise | pallas | ref
+    decode_attn_impl: str = "ref"   # ref | seqshard
+    moe_impl: str = "auto"          # auto | dropless | ep | dense
+    # ssd_scan_pallas and moe_gmm_pallas have no VJP (kernels/ops.py): the
+    # training step takes their XLA paths
     ssd_impl: str = "xla"
-    norm_impl: str = "xla"
-    gmm_impl: str = "auto"
+    norm_impl: str = "auto"
+    gmm_impl: str = "xla"
     tp_axis: str = "model"
     batch_axes: Tuple[str, ...] = ("pod", "data")
     remat_policy: str = "full"      # none | full | dots
@@ -149,8 +162,7 @@ def block_apply(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
     if fam == "hybrid":
         a = attn.attn_apply(p["attn"], h, cfg, window=window,
                             impl=ctx.attn_impl, prefix=cfg.n_meta_tokens,
-                            mesh=ctx.mesh, tp_axis=ctx.tp_axis,
-                            batch_axes=ctx.batch_axes)
+                            mesh=ctx.mesh)
         s = ssm.ssm_apply(p["ssm"], h, cfg, impl=ctx.ssd_impl)
         mix = (layers.rmsnorm(a, p["branch_norm_attn"], cfg.norm_eps,
                               ctx.norm_impl)
@@ -167,8 +179,7 @@ def block_apply(p, x, cfg: ModelConfig, ctx: ModelCtx, window,
                                 mesh=ctx.mesh)
     else:
         a = attn.attn_apply(p["attn"], h, cfg, window=window,
-                            impl=ctx.attn_impl, mesh=ctx.mesh,
-                            tp_axis=ctx.tp_axis, batch_axes=ctx.batch_axes)
+                            impl=ctx.attn_impl, mesh=ctx.mesh)
     x = x + ctx.act(a, "batch", "seq", "embed_act")
     h2 = layers.rmsnorm(x, p["norm2"], cfg.norm_eps, ctx.norm_impl)
     y, aux, _ = _mlp(p, h2, cfg, ctx, dense_mlp)
@@ -352,7 +363,7 @@ def layer_windows(cfg: ModelConfig) -> jax.Array:
 
 
 def uniform_window(cfg: ModelConfig) -> Optional[int]:
-    """Static window if all layers share one (enables pallas/triangular)."""
+    """Static window if all layers share one (lets the flash kernel take it)."""
     ws = set()
     for i in range(cfg.num_layers):
         if cfg.sliding_window and i not in cfg.global_attn_layers:

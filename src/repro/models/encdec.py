@@ -1,8 +1,8 @@
 """Encoder-decoder transformer (whisper-large-v3 backbone).
 
 The conv/mel frontend is a STUB per the assignment: the encoder consumes
-precomputed frame embeddings [B, enc_seq, d] (``input_specs()`` supplies
-them). Encoder = bidirectional MHA + GELU MLP with learned positions;
+precomputed frame embeddings [B, enc_seq, d], which the caller
+supplies. Encoder = bidirectional MHA + GELU MLP with learned positions;
 decoder = causal self-attention (RoPE) + cross-attention + GELU MLP.
 
 Decode carries a self-KV cache plus per-layer *precomputed* cross K/V

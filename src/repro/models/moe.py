@@ -36,8 +36,7 @@ alongside. Shared experts (one SwiGLU MLP) add to every token's output.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -324,19 +323,24 @@ def expert_load(idx, E: int) -> jax.Array:
 
 
 @jax.named_scope("mlp")
-def moe_apply(p, x, cfg: ModelConfig, *, impl: str = "ep", mesh=None,
+def moe_apply(p, x, cfg: ModelConfig, *, impl: str = "auto", mesh=None,
               tp_axis: str = "model", batch_axes=("pod", "data"),
               gmm_impl: str = "auto"
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """x [B,S,d] -> (y, aux loss, tokens routed to each expert [E])."""
+    """x [B,S,d] -> (y, aux loss, tokens routed to each expert [E]).
+    ``impl="auto"`` is ``"ep"`` under a mesh and ``"dropless"`` without."""
+    if impl == "auto":
+        impl = "ep" if mesh is not None else "dropless"
     if impl == "dropless":
         y, aux, load = moe_apply_dropless(p, x, cfg, gmm_impl=gmm_impl)
     else:
         if impl == "dense":
             y, aux = moe_apply_dense(p, x, cfg)
-        else:
+        elif impl == "ep":
             y, aux = moe_apply_ep(p, x, cfg, mesh, tp_axis=tp_axis,
                                   batch_axes=batch_axes, gmm_impl=gmm_impl)
+        else:
+            raise ValueError(f"unknown moe impl {impl!r}")
         load = expert_load(_route(p, x, cfg)[1], cfg.num_experts)
     if "shared" in p:
         with jax.named_scope("moe_shared"):

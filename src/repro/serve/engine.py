@@ -1,7 +1,7 @@
 """Batched prefill + decode engine.
 
 ``serve_step`` (one token for the whole batch against the KV cache) is
-the unit the decode-shape dry-runs lower. ``generate`` is ``prefill``
+the program a decode step runs. ``generate`` is ``prefill``
 then the decode loop; ``decode`` runs the same loop from a cache and a
 position the caller keeps, so that further turns reuse a resident,
 prefilled cache (``prefill`` can fill a chunk of its rows in place).

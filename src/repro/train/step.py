@@ -17,24 +17,20 @@ Every step's metrics carry the md5s of the code that produced them
 """
 from __future__ import annotations
 
-import functools
 import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, RunConfig
+from repro.configs.base import RunConfig
 from repro.core.registry import Binding
 from repro.core.telemetry import Metrics, rebuild_span, timed
 from repro.core.tracing import SpanRecorder
 from repro.models.blocks import ModelCtx
 from repro.optim.api import Optimizer
 from repro.optim.clip import clip_by_global_norm
-from repro.optim.compression import (
-    build_compressor,
-    compression_init,
-)
+from repro.optim.compression import build_compressor
 from repro.sharding.auto import run_rules
 from repro.train.state import TrainState
 
@@ -65,26 +61,18 @@ def default_metrics(logits: jax.Array, labels: jax.Array
 
 def build_ctx(cfg: RunConfig, mesh=None, rules=None,
               decode: bool = False) -> ModelCtx:
+    """``ModelCtx`` for a run: its mesh, rules and axes, and for
+    ``decode`` the decode step's kernels."""
     if rules is None and mesh is not None:
         rules = run_rules(cfg)
-    return ModelCtx(
-        mesh=mesh,
-        rules=rules,
-        attn_impl=cfg.sharding.attn_impl,
-        decode_attn_impl="seqshard" if (decode and mesh is not None
-                                        and cfg.shape.kind == "decode")
-        else "dense",
-        moe_impl=cfg.sharding.moe_impl if cfg.sharding.moe_impl != "gshard"
-        else ("ep" if mesh is not None else "dropless"),
-        # ssd_scan_pallas and moe_gmm_pallas have no VJP (kernels/ops.py):
-        # the training step takes their XLA paths
-        ssd_impl="auto" if decode else "xla",
-        norm_impl="auto",
-        gmm_impl="auto" if decode else "xla",
-        tp_axis=cfg.sharding.tp_axis,
-        batch_axes=cfg.sharding.batch_axes,
-        remat_policy=cfg.train.remat_policy,
-    )
+    kernels = {}
+    if decode:
+        kernels = dict(ssd_impl="auto", gmm_impl="auto")
+        if mesh is not None and cfg.shape.kind == "decode":
+            kernels["decode_attn_impl"] = "seqshard"
+    return ModelCtx(mesh=mesh, rules=rules, tp_axis=cfg.sharding.tp_axis,
+                    batch_axes=cfg.sharding.batch_axes,
+                    remat_policy=cfg.train.remat_policy, **kernels)
 
 
 def model_forward(model, params, batch: Dict[str, jax.Array], ctx: ModelCtx

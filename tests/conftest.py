@@ -2,6 +2,6 @@ import os
 import sys
 
 # NOTE: do NOT set --xla_force_host_platform_device_count here — smoke
-# tests and benches must see 1 device (per the dry-run contract). Tests
+# tests and benches must see 1 device, as a one-chip run does. Tests
 # that need a multi-device mesh spawn a subprocess with XLA_FLAGS set.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

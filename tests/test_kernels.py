@@ -151,17 +151,19 @@ def test_flash_blocks_fit_vmem_at_chip_widths():
 
 
 @pytest.mark.parametrize("B,Hq,Hkv,Sq,Skv,D,causal,window", ATTN_CASES)
-@pytest.mark.parametrize("triangular", [False, True])
+@pytest.mark.parametrize("entry", ["xla", "ops_auto"])
 def test_blockwise_xla_vs_ref(B, Hq, Hkv, Sq, Skv, D, causal, window,
-                              triangular):
-    if triangular and (not causal):
-        pytest.skip("triangular schedule is causal-only")
+                              entry):
+    """The blockwise path, called directly and through
+    ``ops.attention(impl="auto")``, which takes it off a TPU."""
+    attend = {"xla": xla.attention_blockwise,
+              "ops_auto": lambda *a, **kw: ops.attention(*a, impl="auto",
+                                                         **kw)}[entry]
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (B, Hq, Sq, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, Hkv, Skv, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, Hkv, Skv, D), jnp.float32)
-    got = xla.attention_blockwise(q, k, v, causal=causal, window=window,
-                                  block_kv=64, triangular=triangular)
+    got = attend(q, k, v, causal=causal, window=window, block_kv=64)
     want = ref.attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -386,3 +388,12 @@ def test_ops_attention_auto_dispatch(monkeypatch, kw):
     q, k = _qualifying()
     ops.attention(q, k, k, impl="auto", **kw)
     assert calls == ["flash" if not kw else "blockwise"]
+
+
+@pytest.mark.parametrize("impl", ["dense", "blockwise_tri", "xla"])
+def test_ops_attention_refuses_other_names(impl):
+    """Attention takes auto, pallas, ref and blockwise only; any other
+    name is refused, not run as the blockwise path."""
+    q, k = _qualifying()
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        ops.attention(q, k, k, impl=impl)

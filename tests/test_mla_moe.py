@@ -134,6 +134,20 @@ def test_shared_experts_add_to_every_token():
                                atol=1e-5, rtol=1e-5)
 
 
+def test_moe_auto_is_dropless_without_a_mesh():
+    """``impl="auto"``, ``ModelCtx``'s default, takes the dropless path
+    where no mesh is given; a name no path has is refused."""
+    cfg = _moonlight()
+    p = moe_mod.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 8, cfg.d_model))
+    auto = moe_mod.moe_apply(p, x, cfg, gmm_impl="xla")
+    dropless = moe_mod.moe_apply(p, x, cfg, impl="dropless", gmm_impl="xla")
+    for a, b in zip(auto, dropless):
+        assert jnp.array_equal(a, b)
+    with pytest.raises(ValueError, match="unknown moe impl"):
+        moe_mod.moe_apply(p, x, cfg, impl="ep_shardmap")
+
+
 def test_leading_dense_layer_is_its_own_stack():
     cfg = _moonlight()
     model = build_model(cfg)

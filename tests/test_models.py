@@ -13,7 +13,7 @@ from repro.models import build_model
 from repro.models import moe as moe_mod
 from repro.models.blocks import ModelCtx
 
-CTX = ModelCtx(attn_impl="blockwise", decode_attn_impl="dense",
+CTX = ModelCtx(attn_impl="blockwise", decode_attn_impl="ref",
                moe_impl="dense", remat_policy="none")
 B, S = 2, 32
 

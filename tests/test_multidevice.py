@@ -1,5 +1,5 @@
 """Multi-device distribution checks (subprocess: the main pytest process
-must keep 1 device per the dry-run contract)."""
+must keep 1 device, as a one-chip run does)."""
 import os
 import subprocess
 import sys

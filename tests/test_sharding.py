@@ -80,16 +80,24 @@ def test_sp_rules():
     assert r.get("seq") == "model"
 
 
-def test_optimized_preset():
-    base = make_run_config("yi-34b", "train_4k")
-    opt = make_run_config("yi-34b", "train_4k", preset="optimized")
-    assert base.sharding.attn_impl == "auto"
-    assert opt.sharding.attn_impl == "ctxpar"
-    assert opt.train.zero1 and not opt.sharding.fsdp_params
-    # archs without a tuned preset fall back to baseline knobs
-    same = make_run_config("dbrx-132b", "train_4k", preset="optimized")
-    assert same.sharding == make_run_config("dbrx-132b",
-                                            "train_4k").sharding
+def test_build_ctx_takes_the_default_kernels():
+    """A run's context picks the kernels of ``ModelCtx()``; its decode
+    context differs only in the forward-only Pallas paths of ssd and
+    gmm (no mesh, so no sequence-sharded decode attention)."""
+    from dataclasses import fields
+    from repro.models.blocks import ModelCtx
+    from repro.train.step import build_ctx
+
+    run = make_run_config("smollm-135m", "train_4k")
+    kernels = [f.name for f in fields(ModelCtx) if f.name.endswith("_impl")]
+    assert len(kernels) == 6
+    default, train = ModelCtx(), build_ctx(run)
+    decode = build_ctx(run, decode=True)
+    for name in kernels:
+        assert getattr(train, name) == getattr(default, name), name
+    assert {n for n in kernels if getattr(decode, n) != getattr(default, n)
+            } == {"ssd_impl", "gmm_impl"}
+    assert (decode.ssd_impl, decode.gmm_impl) == ("auto", "auto")
 
 
 def test_auto_attention_is_blockwise_off_tpu():
